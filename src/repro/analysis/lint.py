@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.extend.core import Literal
 
 from repro.analysis.cascade import O1, OS, CascadeEntry, REGISTRY
 
@@ -300,7 +301,7 @@ def trace_m_passes(
         return tuple(getattr(atom.aval, "shape", ()))
 
     def read(atom) -> _Info:
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             return _Info(0, 0)
         return env.get(atom, _Info(0, 0))
 
@@ -341,7 +342,7 @@ def trace_m_passes(
         full_reduce = bool(traversed) and not outs_m
         gen = wait + 1
         for a in traversed:
-            if not isinstance(a, jax.core.Literal):
+            if not isinstance(a, Literal):
                 note(a, gen)
         avail = wait + 1 if full_reduce else wait
         ready = wait + 1 if traversed else wait
@@ -373,7 +374,7 @@ def trace_m_passes(
         iterates = bool(traversed)
         gen = wait + 1
         for a in traversed:
-            if not isinstance(a, jax.core.Literal):
+            if not isinstance(a, Literal):
                 note(a, gen)
         out = _Info(gen, gen) if iterates else _Info(wait, wait)
         for ov in eqn.outvars:
@@ -483,7 +484,10 @@ def _probe_decode_paged_pallas(
     entry: CascadeEntry, p: int = 1, quantized: bool = False
 ) -> dict:
     from repro.kernels.decode import fusemax_decode_paged_pallas
-    hkv, g, e, f, page_size, block_k = 2, 8, 16, 16, 32, 16
+    hkv, g, e, f, page_size = 2, 8, 16, 16, 32
+    # quantized pools sweep whole pages (their scale tiles put block_k on
+    # the lane axis)
+    block_k = page_size if quantized else 16
     sigs = []
     for w, lens in ((4, (70, 123)), (8, (150, 247))):
         n_pages = 2 * w + 1
@@ -496,22 +500,23 @@ def _probe_decode_paged_pallas(
         kwargs = {}
         if quantized:
             kwargs = dict(
-                k_scale=jnp.ones((n_pages, page_size, hkv), jnp.float32),
-                v_scale=jnp.ones((n_pages, page_size, hkv), jnp.float32))
+                k_scale=jnp.ones((n_pages, hkv, page_size), jnp.float32),
+                v_scale=jnp.ones((n_pages, hkv, page_size), jnp.float32))
         with capture_pallas_calls() as recs:
             fusemax_decode_paged_pallas(
                 jnp.zeros((2 * hkv, g, e), jnp.float32),
-                jnp.zeros((n_pages, page_size, hkv, e), jnp.float32),
-                jnp.zeros((n_pages, page_size, hkv, f), jnp.float32),
+                jnp.zeros((n_pages, hkv, page_size, e), jnp.float32),
+                jnp.zeros((n_pages, hkv, page_size, f), jnp.float32),
                 jnp.asarray(table), kv_len, scale=0.25, hkv=hkv,
                 splits=2, block_k=block_k, p=p, **kwargs)
         (rec,) = recs
         split_len = (w // 2) * page_size
 
-        for bh in range(rec.grid[0]):
-            limit = int(lens[bh // hkv]) + (p - 1)
+        # one grid row per sequence: every kv head rides the same tile
+        for b in range(rec.grid[0]):
+            limit = int(lens[b]) + (p - 1)
 
-            def live(b, s, m2, _lim=limit):
+            def live(b_i, s, m2, _lim=limit):
                 return s * split_len + m2 * block_k < _lim
 
             n_tiles = -(-limit // block_k)
@@ -519,9 +524,10 @@ def _probe_decode_paged_pallas(
             if quantized:
                 streams += [(3, "k_scale"), (4, "v_scale")]
             for si, what in streams:
-                assert_single_sweep(rec, si, {0: bh}, n_tiles, live, what)
-            assert_stationary(rec, 0, sweep_axis=2, fixed={0: bh, 1: 0})
-        assert_scratch(rec, [(g, _LANES), (g, _LANES), (g, f)], "RM/RD/RNV")
+                assert_single_sweep(rec, si, {0: b}, n_tiles, live, what)
+            assert_stationary(rec, 0, sweep_axis=2, fixed={0: b, 1: 0})
+        assert_scratch(rec, [(hkv, g, _LANES), (hkv, g, _LANES),
+                             (hkv, g, f)], "RM/RD/RNV per kv head")
         sigs.append(rec.scratch_sig())
     assert_s_independent(sigs, entry.name)
     return {"probe": f"pallas:decode_paged[p={p},quant={quantized}]",

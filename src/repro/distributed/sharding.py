@@ -52,37 +52,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_fn():
-    """The ``shard_map`` entry point across supported jax versions:
-    ``jax.shard_map`` on jax >= 0.6, ``jax.experimental.shard_map`` on the
-    0.4.x line.  Returns a ``wrap(f, mesh=, in_specs=, out_specs=)``
-    callable with the static replication check disabled — the paged
-    attention paths all-gather head shards back to replicated outputs,
-    which the 0.4.x checker cannot statically infer (the kwarg is
-    ``check_rep`` there, ``check_vma`` on new jax, hence the probe)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-
-    def wrap(f, *, mesh, in_specs, out_specs):
-        # the check kwarg must actually be disabled — constructing with
-        # the default check enabled would only defer the failure to an
-        # opaque trace-time replication error, so an unknown signature
-        # raises here instead of falling back
-        for kw in ({"check_rep": False}, {"check_vma": False}):
-            try:
-                return sm(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-        raise RuntimeError(
-            f"shard_map on jax {jax.__version__} accepts neither "
-            "check_rep nor check_vma — the kwarg was renamed again; "
-            "update repro.distributed.sharding.shard_map_fn")
-
-    return wrap
-
-
 @dataclasses.dataclass(frozen=True)
 class KVShard:
     """Device sharding of the paged KV pool: pages split along the kv-head
@@ -142,15 +111,15 @@ def validate_kv_shard(cfg, tp: int) -> None:
 
 
 #: paged-cache leaf name → the dimension (from the right) that shards:
-#: GQA page arrays are [..., P, page_size, Hkv, dh] (head axis at -2);
-#: MLA latent pages are [..., P, page_size, r] (rank axis at -1).
-#: Quantized-pool GQA scale pools [..., P, page_size, Hkv] shard on the
-#: head axis (-1); MLA scale pools [..., P, page_size] carry one scalar
+#: GQA page arrays are head-major [..., P, Hkv, page_size, dh] (head axis
+#: at -3); MLA latent pages are [..., P, page_size, r] (rank axis at -1).
+#: Quantized-pool GQA scale pools [..., P, Hkv, page_size] shard on the
+#: head axis (-2); MLA scale pools [..., P, page_size] carry one scalar
 #: per full latent vector — no shardable axis — so they stay replicated
 #: by falling through to the default branch below.
-_PAGED_SHARD_DIMS = {"k_pages": -2, "v_pages": -2,
+_PAGED_SHARD_DIMS = {"k_pages": -3, "v_pages": -3,
                      "ckv_pages": -1, "krope_pages": -1,
-                     "k_scale": -1, "v_scale": -1}
+                     "k_scale": -2, "v_scale": -2}
 
 
 def paged_cache_shardings(caches, shard: KVShard):
@@ -343,18 +312,21 @@ def replica_device_groups(dp: int, tp: int = 1,
     replica's TP collectives on neighbouring chips (ICI-local on TPU
     slices) while replicas never communicate — routing is host-side.
 
-    With fewer than ``dp*tp`` devices and ``tp == 1`` the groups wrap
-    round-robin (CPU smoke: every replica shares device 0 — correctness
-    and routing behaviour are unchanged, only true parallel speedup is
-    lost).  With ``tp > 1`` the device count must cover every group.
+    The device count must cover every group.  The one exception is
+    ``tp == 1`` on CPU devices, where the groups wrap round-robin (CPU
+    smoke: every replica shares device 0 — correctness and routing
+    behaviour are unchanged, only true parallel speedup is lost); on an
+    accelerator, stacking replicas onto one chip would silently divide
+    its memory, so that raises too.
     """
     if dp < 1 or tp < 1:
         raise ValueError(f"need dp >= 1 and tp >= 1, got dp={dp} tp={tp}")
     devs = list(devices) if devices is not None else list(jax.devices())
     need = dp * tp
     if len(devs) < need:
-        if tp > 1:
+        if tp > 1 or devs[0].platform != "cpu":
             raise ValueError(
-                f"dp={dp} tp={tp} needs {need} devices, have {len(devs)}")
+                f"dp={dp} tp={tp} needs {need} {devs[0].platform} "
+                f"devices, have {len(devs)}")
         return [[devs[i % len(devs)]] for i in range(dp)]
     return [devs[i * tp:(i + 1) * tp] for i in range(dp)]

@@ -19,7 +19,8 @@ from repro.kernels.decode import (
 )
 from repro.kernels.ops import (
     fusemax_attention, fusemax_decode, fusemax_decode_paged,
-    fusemax_mla_decode_paged, gather_pages, mla_combine_partials,
+    fusemax_mla_decode_paged, gather_kv_pages, gather_pages,
+    mla_combine_partials,
     mla_decode_partials,
 )
 from repro.kernels.ref import decode_reference, mha_reference
@@ -32,6 +33,7 @@ __all__ = [
     "decode_params",
     "decode_reference",
     "exp_maccs",
+    "gather_kv_pages",
     "gather_pages",
     "measure_best",
     "mla_combine_partials",

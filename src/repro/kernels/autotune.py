@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import logging
 import os
 import statistics
 import time
@@ -32,6 +33,7 @@ from typing import Callable, Optional, Sequence
 from repro.analysis.accel_model import SpatialArch
 
 _ARCH = SpatialArch()
+_log = logging.getLogger(__name__)
 
 #: VMEM budget for one kernel instance (bytes).  Half of a 16 MiB TPU VMEM
 #: — the other half is Pallas' automatic double-buffering of the K/V
@@ -351,9 +353,10 @@ def measure_best(
     ``(best_candidate, {candidate: seconds})``.
 
     ``make_fn(candidate)`` must return a callable taking ``*args``; timing
-    follows :func:`time_fn`.  When ``key`` is given, the winner is written
-    into the autotune table (and the on-disk cache if
-    ``REPRO_AUTOTUNE_CACHE`` is set) so subsequent
+    follows :func:`time_fn`.  A candidate that fails to build or run is
+    skipped with a warning that names it and the error.  When ``key`` is
+    given, the winner is written into the autotune table (and the on-disk
+    cache if ``REPRO_AUTOTUNE_CACHE`` is set) so subsequent
     :func:`attention_params` / :func:`decode_params` lookups return it.
     """
     timings: dict = {}
@@ -361,7 +364,9 @@ def measure_best(
         try:
             timings[cand] = time_fn(make_fn(cand), *args,
                                     iters=iters, warmup=warmup)
-        except Exception:                        # infeasible candidate
+        except Exception as err:                 # infeasible candidate
+            _log.warning("measure_best: skipping candidate %r: %s: %s",
+                         cand, type(err).__name__, err)
             timings[cand] = float("inf")
     best = min(timings, key=timings.get)
     if timings[best] == float("inf"):

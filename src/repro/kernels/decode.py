@@ -13,18 +13,19 @@ Ragged KV lengths (each sequence in the batch has its own valid prefix of
 the cache) arrive via scalar prefetch (SMEM) and mask the tail chunks.
 
 The *paged* variant (``fusemax_decode_paged_pallas``) reads K/V from a
-page pool ``[num_pages, page_size, Hkv, E]`` through a per-sequence block
-table instead of a dense cache: the block table rides in as a second
-scalar-prefetch operand and the K/V ``index_map``s resolve each tile's
-page id from it, so the sweep touches only the pages the sequence owns.
+head-major page pool ``[num_pages, Hkv, page_size, E]`` through a
+per-sequence block table instead of a dense cache: the block table rides
+in as a second scalar-prefetch operand and the K/V ``index_map``s resolve
+each tile's page id from it, so the sweep touches only the pages the
+sequence owns.  One grid step covers every kv head of a page slice.
 Split boundaries stay page-aligned (``splits`` divides the table width,
 ``block_k`` divides ``page_size``) and the partials combine with the same
 associative running-max algebra — the cascade is indifferent to where the
 keys physically live.
 
-Both kernels are grid-parallel over B·Hkv fibers with no cross-head
-communication, which is what lets the serving tier run them on kv-head
-*shards* of a device-partitioned page pool (``shard_map`` in
+Both kernels are grid-parallel over (batch, kv-head) fibers with no
+cross-head communication, which is what lets the serving tier run them
+on kv-head *shards* of a device-partitioned page pool (``shard_map`` in
 ``repro.model.attention``): a shard's ``hkv`` is just a smaller fiber
 count, the block table and page ids are global, and per-fiber results
 match the full-pool run bit-for-bit.
@@ -54,7 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.einsum import Cascade, Einsum, T
-from repro.kernels.fusemax import CompilerParams, LANES, NEG_INF, _exp
+from repro.kernels.fusemax import LANES, NEG_INF, _exp
 
 
 def _decode_partials_kernel(
@@ -223,7 +224,7 @@ def fusemax_decode_pallas(
             jax.ShapeDtypeStruct((bh, splits, g, LANES), jnp.float32),
             jax.ShapeDtypeStruct((bh, splits, g, f), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -245,6 +246,31 @@ def _combine_partials(pm, pl_, pnv, dtype):
     return (rnv / rd[..., None]).astype(dtype)
 
 
+def _scale_operand(scale: jnp.ndarray) -> jnp.ndarray:
+    """fp16 scale pools enter the kernels bit-cast to int16: Mosaic cannot
+    load fp16 vectors on TPU, and a same-width bitcast costs no copy."""
+    if scale.dtype == jnp.float16:
+        return jax.lax.bitcast_convert_type(scale, jnp.int16)
+    return scale
+
+
+def _scale_f32(tile: jnp.ndarray) -> jnp.ndarray:
+    """A scale tile as fp32, decoding the fp16 bits of an int16 tile (see
+    :func:`_scale_operand`).  Stored scales are finite, so the decode
+    covers normal and subnormal fp16 values only."""
+    if tile.dtype != jnp.int16:
+        return tile.astype(jnp.float32)
+    bits = tile.astype(jnp.int32) & 0xFFFF
+    sign = bits >> 15
+    expo = (bits >> 10) & 0x1F
+    mant = bits & 0x3FF
+    normal = jax.lax.bitcast_convert_type(
+        (sign << 31) | ((expo + 112) << 23) | (mant << 13), jnp.float32)
+    sub = mant.astype(jnp.float32) * (2.0 ** -24)
+    sub = jnp.where(sign == 1, -sub, sub)
+    return jnp.where(expo == 0, sub, normal)
+
+
 def _paged_decode_partials_kernel(
     kv_len_ref,                     # SMEM scalar-prefetch: [B] int32
     bt_ref,                         # SMEM scalar-prefetch: [B, W] int32
@@ -252,7 +278,6 @@ def _paged_decode_partials_kernel(
     *refs,                          # [ks_ref, vs_ref,] outputs, scratch
     scale: float,
     softcap: Optional[float],
-    hkv: int,
     block_k: int,
     m2_total: int,
     split_len: int,
@@ -261,15 +286,18 @@ def _paged_decode_partials_kernel(
     rows_per_pos: int = 0,
     quantized: bool = False,
 ):
-    """Same running-state sweep as :func:`_decode_partials_kernel`, but the
-    K/V tiles were block-selected through the block table (see the
-    ``index_map``s in :func:`fusemax_decode_paged_pallas`); the kernel body
-    itself only needs the *logical* token index for ragged masking.
+    """Same running-state sweep as :func:`_decode_partials_kernel`, for
+    every kv head of one sequence at once: the K/V tiles
+    ``[Hkv, block_k, E]`` were block-selected through the block table (see
+    the ``index_map``s in :func:`fusemax_decode_paged_pallas`), the scores
+    and the accumulator are head-batched matmuls, and the kernel body only
+    needs the *logical* token index for ragged masking.
 
-    With ``quantized=True`` two extra fp32 scale tiles ride along (same
-    block-table lookup, one scalar per (token, kv-head)) and the K/V tiles
-    are dequantized in-register right after the VMEM load — the score GEMM
-    and the cascade always run on fp32 operands."""
+    With ``quantized=True`` two scale tiles ``[Hkv, block_k]`` (one scalar
+    per (kv head, token)) ride along.  A per-token K scale multiplies that
+    token's score column and a per-token V scale its probability column,
+    so both apply along the lane axis and the GEMMs run on the raw codes
+    (cast to fp32) — the same arithmetic as dequantizing the tiles first."""
     if quantized:
         (ks_ref, vs_ref, pm_ref, pl_ref, pnv_ref,
          m_scratch, l_scratch, acc_scratch) = refs
@@ -277,11 +305,11 @@ def _paged_decode_partials_kernel(
         ks_ref = vs_ref = None
         (pm_ref, pl_ref, pnv_ref,
          m_scratch, l_scratch, acc_scratch) = refs
-    bh = pl.program_id(0)
+    b = pl.program_id(0)
     s = pl.program_id(1)
     m2 = pl.program_id(2)
 
-    kv_len = kv_len_ref[bh // hkv]           # valid logical prefix
+    kv_len = kv_len_ref[b]                   # valid logical prefix
 
     @pl.when(m2 == 0)
     def _init():
@@ -294,55 +322,57 @@ def _paged_decode_partials_kernel(
 
     @pl.when(run)
     def _body():
-        q_tile = q_ref[0].astype(jnp.float32)            # [G, E]
-        k_tile = k_ref[0, :, 0].astype(jnp.float32)      # [block_k, E]
-        v_tile = v_ref[0, :, 0].astype(jnp.float32)      # [block_k, F]
-        if ks_ref is not None:
-            k_tile = k_tile * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v_tile = v_tile * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
+        q_tile = q_ref[...].astype(jnp.float32)          # [Hkv, G, E]
+        k_tile = k_ref[0].astype(jnp.float32)            # [Hkv, block_k, E]
+        v_tile = v_ref[0].astype(jnp.float32)            # [Hkv, block_k, F]
 
         sc = jax.lax.dot_general(
-            q_tile, k_tile, (((1,), (1,)), ((), ())),
+            q_tile, k_tile, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale                                        # [G, block_k]
+        )                                                # [Hkv, G, block_k]
+        if ks_ref is not None:
+            sc = sc * _scale_f32(ks_ref[0])[:, None, :]
+        sc = sc * scale
         if softcap is not None:
             sc = softcap * jnp.tanh(sc / softcap)
 
-        cols = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        cols = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
         if n_pos == 1:
             ok = (k_lo + cols) < kv_len                  # ragged mask
         else:
             # causal intra-draft mask: folded row r is draft position
             # r // rows_per_pos and sees keys < kv_len + position
-            rows = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+            rows = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
             ok = (k_lo + cols) < kv_len + rows // rows_per_pos
         sc = jnp.where(ok, sc, NEG_INF)
 
-        m_prev = m_scratch[:, :1]
-        lm = jnp.max(sc, axis=1, keepdims=True)
+        m_prev = m_scratch[:, :, :1]
+        lm = jnp.max(sc, axis=2, keepdims=True)
         m_new = jnp.maximum(m_prev, lm)
         p = _exp(sc - m_new, exp_impl)
-        sld = jnp.sum(p, axis=1, keepdims=True)
+        sld = jnp.sum(p, axis=2, keepdims=True)
         prm = _exp(m_prev - m_new, exp_impl)
         l_scratch[...] = jnp.broadcast_to(
-            l_scratch[:, :1] * prm + sld, l_scratch.shape)
+            l_scratch[:, :, :1] * prm + sld, l_scratch.shape)
+        if vs_ref is not None:
+            p = p * _scale_f32(vs_ref[0])[:, None, :]
         acc_scratch[...] = acc_scratch[...] * prm + jax.lax.dot_general(
-            p, v_tile, (((1,), (0,)), ((), ())),
+            p, v_tile, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         m_scratch[...] = jnp.broadcast_to(m_new, m_scratch.shape)
 
     @pl.when(m2 == m2_total - 1)
     def _finish():
-        pm_ref[0, 0] = m_scratch[...].astype(pm_ref.dtype)
-        pl_ref[0, 0] = l_scratch[...].astype(pl_ref.dtype)
-        pnv_ref[0, 0] = acc_scratch[...].astype(pnv_ref.dtype)
+        pm_ref[:, 0] = m_scratch[...].astype(pm_ref.dtype)
+        pl_ref[:, 0] = l_scratch[...].astype(pl_ref.dtype)
+        pnv_ref[:, 0] = acc_scratch[...].astype(pnv_ref.dtype)
 
 
 def fusemax_decode_paged_pallas(
     q: jnp.ndarray,            # [BHkv, G, E]  (G padded ≥ 8)
-    k_pages: jnp.ndarray,      # [P, page_size, Hkv, E]
-    v_pages: jnp.ndarray,      # [P, page_size, Hkv, F]
+    k_pages: jnp.ndarray,      # [P, Hkv, page_size, E]
+    v_pages: jnp.ndarray,      # [P, Hkv, page_size, F]
     block_table: jnp.ndarray,  # [B, W] int32 page ids
     kv_len: jnp.ndarray,       # [B] int32 valid logical lengths
     *,
@@ -354,23 +384,29 @@ def fusemax_decode_paged_pallas(
     exp_impl: str = "native",
     interpret: bool = False,
     p: int = 1,
-    k_scale: Optional[jnp.ndarray] = None,   # [P, page_size, Hkv] fp32
+    k_scale: Optional[jnp.ndarray] = None,   # [P, Hkv, page_size]
     v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Paged split-K FuseMax decode. Returns [BHkv, G, F] (q.dtype).
     With ``p > 1`` the G axis folds a verify chain (see the dense kernel).
 
-    The grid sweeps logical token chunks; each K/V tile's physical page is
-    looked up in the block table inside the ``index_map`` (standard paged
-    attention: the gather happens in the pipeline's block fetch, never as
-    a materialized [B, T, E] copy).
+    The pools are head-major (``[P, Hkv, page_size, E]``), and one grid
+    step loads a ``[Hkv, block_k, E]`` tile — every kv head of one page
+    slice — so each block's last two dims are (``block_k``, full ``E``),
+    which the TPU tiling accepts.  The grid sweeps logical token chunks
+    per sequence; each tile's physical page is looked up in the block
+    table inside the ``index_map`` (standard paged attention: the gather
+    happens in the pipeline's block fetch, never as a materialized
+    [B, T, E] copy).
 
-    ``k_scale``/``v_scale`` (quantized pools) stream per-token fp32 scale
-    tiles through the same block-table ``index_map`` and the kernel
-    dequantizes in-register before the score GEMM.
+    ``k_scale``/``v_scale`` (quantized pools) stream per-token scale
+    tiles ``[Hkv, block_k]`` through the same block-table ``index_map``.
+    A scale tile's last dim must be the whole page or a multiple of 128
+    lanes, so quantized pools need ``block_k == page_size`` or
+    ``block_k % 128 == 0``.
     """
     bh, g, e = q.shape
-    n_pages, page_size, hkv_p, f = v_pages.shape
+    n_pages, hkv_p, page_size, f = v_pages.shape
     b, w = block_table.shape
     if g % p:
         raise ValueError(f"folded q rows {g} not divisible by p={p}")
@@ -383,18 +419,21 @@ def fusemax_decode_paged_pallas(
     block_k = min(block_k, page_size)
     if page_size % block_k:
         raise ValueError(f"page_size={page_size} % block_k={block_k}")
+    quantized = k_scale is not None
+    if quantized and block_k != page_size and block_k % 128:
+        raise ValueError(
+            f"quantized pools tile scales [Hkv, block_k]: block_k={block_k} "
+            f"must equal page_size={page_size} or be a multiple of 128")
     split_pages = w // splits
     split_len = split_pages * page_size
     blocks_per_page = page_size // block_k
     m2 = split_pages * blocks_per_page
-    grid = (bh, splits, m2)
+    grid = (b, splits, m2)
 
-    quantized = k_scale is not None
     kernel = functools.partial(
         _paged_decode_partials_kernel,
         scale=scale,
         softcap=softcap,
-        hkv=hkv,
         block_k=block_k,
         m2_total=m2,
         split_len=split_len,
@@ -404,48 +443,48 @@ def fusemax_decode_paged_pallas(
         quantized=quantized,
     )
 
-    def _kv_index(bh_i, s, m2_i, kv_len_ref, bt_ref):
+    def _page(b_i, s, m2_i, bt_ref):
         page_slot = s * split_pages + m2_i // blocks_per_page
         # unbacked table rows hold the out-of-range sentinel id (P):
         # clamp the DMA to the last page — those tiles are masked by
         # kv_len in the kernel body, so the content never contributes
-        page = jnp.minimum(bt_ref[bh_i // hkv, page_slot], n_pages - 1)
-        return (page, m2_i % blocks_per_page, bh_i % hkv, 0)
+        return jnp.minimum(bt_ref[b_i, page_slot], n_pages - 1)
 
-    def _scale_index(bh_i, s, m2_i, kv_len_ref, bt_ref):
-        page_slot = s * split_pages + m2_i // blocks_per_page
-        page = jnp.minimum(bt_ref[bh_i // hkv, page_slot], n_pages - 1)
-        return (page, m2_i % blocks_per_page, bh_i % hkv)
+    def _kv_index(b_i, s, m2_i, kv_len_ref, bt_ref):
+        return (_page(b_i, s, m2_i, bt_ref), 0, m2_i % blocks_per_page, 0)
+
+    def _scale_index(b_i, s, m2_i, kv_len_ref, bt_ref):
+        return (_page(b_i, s, m2_i, bt_ref), 0, m2_i % blocks_per_page)
 
     in_specs = [
-        pl.BlockSpec((1, g, e), lambda b_i, s, m2_i, *_: (b_i, 0, 0)),
-        pl.BlockSpec((1, block_k, 1, e), _kv_index),
-        pl.BlockSpec((1, block_k, 1, f), _kv_index),
+        pl.BlockSpec((hkv, g, e), lambda b_i, s, m2_i, *_: (b_i, 0, 0)),
+        pl.BlockSpec((1, hkv, block_k, e), _kv_index),
+        pl.BlockSpec((1, hkv, block_k, f), _kv_index),
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, block_k, 1), _scale_index),
-            pl.BlockSpec((1, block_k, 1), _scale_index),
+            pl.BlockSpec((1, hkv, block_k), _scale_index),
+            pl.BlockSpec((1, hkv, block_k), _scale_index),
         ]
-        operands += [k_scale, v_scale]
+        operands += [_scale_operand(k_scale), _scale_operand(v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, g, LANES),
+            pl.BlockSpec((hkv, 1, g, LANES),
                          lambda b_i, s, m2_i, *_: (b_i, s, 0, 0)),
-            pl.BlockSpec((1, 1, g, LANES),
+            pl.BlockSpec((hkv, 1, g, LANES),
                          lambda b_i, s, m2_i, *_: (b_i, s, 0, 0)),
-            pl.BlockSpec((1, 1, g, f),
+            pl.BlockSpec((hkv, 1, g, f),
                          lambda b_i, s, m2_i, *_: (b_i, s, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, f), jnp.float32),
+            pltpu.VMEM((hkv, g, LANES), jnp.float32),
+            pltpu.VMEM((hkv, g, LANES), jnp.float32),
+            pltpu.VMEM((hkv, g, f), jnp.float32),
         ],
     )
 
@@ -457,7 +496,7 @@ def fusemax_decode_paged_pallas(
             jax.ShapeDtypeStruct((bh, splits, g, LANES), jnp.float32),
             jax.ShapeDtypeStruct((bh, splits, g, f), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -628,10 +667,11 @@ def _mla_paged_decode_partials_kernel(
     sum of two dots (latent and rope halves) and the value stream IS the
     latent tile — the accumulator lives in rank-space.
 
-    With ``quantized=True`` two per-token fp32 scale tiles (one scalar
-    per latent vector / rope vector) ride along and the page tiles are
-    dequantized in-register right after the load — the dequantized latent
-    tile feeds both the score dot and the rank-space accumulator."""
+    With ``quantized=True`` two per-token scale tiles ``[1, block_k]``
+    (one scalar per latent vector / rope vector) ride along.  They scale
+    the score columns of their dot and, for the latent scale, the
+    probability columns of the rank-space accumulation — the arithmetic
+    of dequantizing the tiles first, applied along the lane axis."""
     if quantized:
         (cs_ref, krs_ref, pm_ref, pl_ref, pnv_ref,
          m_scratch, l_scratch, acc_scratch) = refs
@@ -658,18 +698,20 @@ def _mla_paged_decode_partials_kernel(
         q_tile = q_ref[0].astype(jnp.float32)            # [G, r + rope]
         ckv_tile = ckv_ref[0].astype(jnp.float32)        # [block_k, r]
         kr_tile = krope_ref[0].astype(jnp.float32)       # [block_k, rope]
-        if cs_ref is not None:
-            ckv_tile = ckv_tile * cs_ref[0].astype(jnp.float32)[:, None]
-            kr_tile = kr_tile * krs_ref[0].astype(jnp.float32)[:, None]
 
-        sc = jax.lax.dot_general(
+        sc_lat = jax.lax.dot_general(
             q_tile[:, :rank], ckv_tile, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) + jax.lax.dot_general(
+        )
+        sc_rope = jax.lax.dot_general(
             q_tile[:, rank:], kr_tile, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        sc = sc * scale                                  # [G, block_k]
+        if cs_ref is not None:
+            cs = _scale_f32(cs_ref[0])                   # [1, block_k]
+            sc_lat = sc_lat * cs
+            sc_rope = sc_rope * _scale_f32(krs_ref[0])
+        sc = (sc_lat + sc_rope) * scale                  # [G, block_k]
         if softcap is not None:
             sc = softcap * jnp.tanh(sc / softcap)
 
@@ -689,6 +731,8 @@ def _mla_paged_decode_partials_kernel(
         prm = _exp(m_prev - m_new, exp_impl)
         l_scratch[...] = jnp.broadcast_to(
             l_scratch[:, :1] * prm + sld, l_scratch.shape)
+        if cs_ref is not None:
+            p = p * cs
         acc_scratch[...] = acc_scratch[...] * prm + jax.lax.dot_general(
             p, ckv_tile, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -716,7 +760,7 @@ def fusemax_mla_decode_paged_pallas(
     exp_impl: str = "native",
     interpret: bool = False,
     p: int = 1,
-    ckv_scale: Optional[jnp.ndarray] = None,   # [P, page_size] fp32
+    ckv_scale: Optional[jnp.ndarray] = None,   # [P, page_size]
     krope_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Paged split-K MLA decode in latent space. Returns [B, G, rank]
@@ -726,6 +770,10 @@ def fusemax_mla_decode_paged_pallas(
     two page streams whose ``index_map``s resolve the same block-table
     slot: the latent pages double as the V stream (F = rank), so per-step
     decode DMAs exactly the slot's pages and nothing else.
+
+    Quantized scale pools enter the kernel as ``[P, 1, page_size]`` views
+    tiled ``(1, 1, block_k)``, so ``block_k`` must be the whole page or a
+    multiple of 128 lanes.
     """
     b, g, e = q.shape
     n_pages, page_size, rank = ckv_pages.shape
@@ -742,13 +790,18 @@ def fusemax_mla_decode_paged_pallas(
     block_k = min(block_k, page_size)
     if page_size % block_k:
         raise ValueError(f"page_size={page_size} % block_k={block_k}")
+    quantized = ckv_scale is not None
+    if quantized and block_k != page_size and block_k % 128:
+        raise ValueError(
+            f"quantized latent pools tile scales [1, block_k]: "
+            f"block_k={block_k} must equal page_size={page_size} or be a "
+            f"multiple of 128")
     split_pages = w // splits
     split_len = split_pages * page_size
     blocks_per_page = page_size // block_k
     m2 = split_pages * blocks_per_page
     grid = (b, splits, m2)
 
-    quantized = ckv_scale is not None
     kernel = functools.partial(
         _mla_paged_decode_partials_kernel,
         scale=scale,
@@ -763,28 +816,32 @@ def fusemax_mla_decode_paged_pallas(
         quantized=quantized,
     )
 
-    def _page_index(b_i, s, m2_i, kv_len_ref, bt_ref):
+    def _page(b_i, s, m2_i, bt_ref):
         page_slot = s * split_pages + m2_i // blocks_per_page
         # sentinel ids (P) on unbacked slots clamp to the last page; the
         # kv_len mask in the body keeps their content out of the cascade
-        page = jnp.minimum(bt_ref[b_i, page_slot], n_pages - 1)
-        return (page, m2_i % blocks_per_page)
+        return jnp.minimum(bt_ref[b_i, page_slot], n_pages - 1)
 
-    def _page_index3(b_i, s, m2_i, kv_len_ref, bt_ref):
-        return (*_page_index(b_i, s, m2_i, kv_len_ref, bt_ref), 0)
+    def _page_index(b_i, s, m2_i, kv_len_ref, bt_ref):
+        return (_page(b_i, s, m2_i, bt_ref), m2_i % blocks_per_page, 0)
+
+    def _scale_index(b_i, s, m2_i, kv_len_ref, bt_ref):
+        return (_page(b_i, s, m2_i, bt_ref), 0, m2_i % blocks_per_page)
 
     in_specs = [
         pl.BlockSpec((1, g, e), lambda b_i, s, m2_i, *_: (b_i, 0, 0)),
-        pl.BlockSpec((1, block_k, rank), _page_index3),
-        pl.BlockSpec((1, block_k, rope_dim), _page_index3),
+        pl.BlockSpec((1, block_k, rank), _page_index),
+        pl.BlockSpec((1, block_k, rope_dim), _page_index),
     ]
     operands = [q, ckv_pages, krope_pages]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, block_k), _page_index),
-            pl.BlockSpec((1, block_k), _page_index),
+            pl.BlockSpec((1, 1, block_k), _scale_index),
+            pl.BlockSpec((1, 1, block_k), _scale_index),
         ]
-        operands += [ckv_scale, krope_scale]
+        operands += [
+            _scale_operand(ckv_scale).reshape(n_pages, 1, page_size),
+            _scale_operand(krope_scale).reshape(n_pages, 1, page_size)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -813,7 +870,7 @@ def fusemax_mla_decode_paged_pallas(
             jax.ShapeDtypeStruct((b, splits, g, LANES), jnp.float32),
             jax.ShapeDtypeStruct((b, splits, g, rank), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

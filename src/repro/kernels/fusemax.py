@@ -58,11 +58,6 @@ def prefill_cascade() -> Cascade:
 LANES = 128          # TPU lane width: scratch kept (block_q, LANES)
 LOG2E = 1.4426950408889634
 
-# jax renamed TPUCompilerParams → CompilerParams across 0.4.x releases;
-# support both so the kernels run on the baked-in toolchain.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 # Taylor coefficients of 2^f = exp(f·ln2) on f ∈ [0, 1): ln2^k / k!.
 # Six multiply-accumulates via Horner — the paper's exp-on-the-MACC-array
 # trick ([36]); max rel. error ≈ 1.4e-5 on [0,1).
@@ -255,7 +250,7 @@ def fusemax_attention_pallas(
             pltpu.VMEM((block_q, LANES), jnp.float32),   # RD
             pltpu.VMEM((block_q, f), jnp.float32),       # RNV
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -481,10 +481,31 @@ def gather_pages(pages: jnp.ndarray, block_table: jnp.ndarray) -> jnp.ndarray:
     return g.reshape(b, -1, *pages.shape[2:])
 
 
+def gather_kv_pages(pages: jnp.ndarray,
+                    block_table: jnp.ndarray) -> jnp.ndarray:
+    """:func:`gather_pages` for a head-major GQA pool: pages
+    [P, Hkv, page_size, *tail] → [B, Hkv, W·page_size, *tail], the dense
+    cache's layout (same sentinel clamp)."""
+    b, w = block_table.shape
+    bt = jnp.minimum(block_table, pages.shape[0] - 1)
+    g = jnp.moveaxis(pages[bt], 2, 1)           # [B, Hkv, W, ps, *tail]
+    return g.reshape(b, pages.shape[1], w * pages.shape[2],
+                     *pages.shape[3:])
+
+
+def _scale_block_k(block_k: int, page_size: int, quantized: bool) -> int:
+    """Quantized pools tile their scale pools with ``block_k`` on the lane
+    axis, which the TPU accepts only as the whole page or a multiple of
+    128: otherwise sweep one whole page per grid step."""
+    if quantized and block_k != page_size and block_k % 128:
+        return page_size
+    return block_k
+
+
 def fusemax_decode_paged(
     q: jnp.ndarray,            # [B, Hq, 1, E]
-    k_pages: jnp.ndarray,      # [P, page_size, Hkv, E]
-    v_pages: jnp.ndarray,      # [P, page_size, Hkv, F]
+    k_pages: jnp.ndarray,      # [P, Hkv, page_size, E]
+    v_pages: jnp.ndarray,      # [P, Hkv, page_size, F]
     block_table: jnp.ndarray,  # [B, W] int32 page ids
     kv_len: jnp.ndarray,       # [B] valid logical lengths
     *,
@@ -496,7 +517,7 @@ def fusemax_decode_paged(
     block_k: Optional[int] = None,
     exp_impl: str = "native",
     interpret: Optional[bool] = None,
-    k_scale: Optional[jnp.ndarray] = None,   # [P, page_size, Hkv] fp32
+    k_scale: Optional[jnp.ndarray] = None,   # [P, Hkv, page_size]
     v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Single-token decode against a *paged* KV cache.
@@ -509,11 +530,11 @@ def fusemax_decode_paged(
     in the index_map, page-aligned splits from the autotuner).
 
     ``k_scale``/``v_scale`` mark the pools as quantized (fp8/int8 codes
-    with per-token-per-head fp32 scales): the jnp/ref paths dequantize the
+    with per-token-per-head scales): the jnp/ref paths dequantize the
     gathered view before delegating, the Pallas path streams the scale
-    tiles into the kernel and dequantizes in-register before the score
-    GEMM.  Scale pools follow the same sentinel/clamp discipline as the
-    data pools, so masking by ``kv_len`` is unchanged.
+    tiles into the kernel, which applies them to the score and
+    probability columns.  Scale pools follow the same sentinel/clamp
+    discipline as the data pools, so masking by ``kv_len`` is unchanged.
 
     Shard contract (device-sharded pools): every computation here is
     independent per (batch, kv-head) fiber and the autotuned
@@ -525,7 +546,7 @@ def fusemax_decode_paged(
     the corresponding head slice of the full-pool call.
     """
     b, hq, p, e = q.shape
-    n_pages, page_size, hkv, f = v_pages.shape
+    n_pages, hkv, page_size, f = v_pages.shape
     w = block_table.shape[1]
     group = hq // hkv
     scale = scale if scale is not None else 1.0 / (e ** 0.5)
@@ -538,11 +559,11 @@ def fusemax_decode_paged(
         # autotuned splits, same arithmetic as the dense layout (P > 1 —
         # the speculative verify chain — rides the same delegation)
         cap = w * page_size if capacity is None else capacity
-        k = jnp.moveaxis(gather_pages(k_pages, block_table), 2, 1)
-        v = jnp.moveaxis(gather_pages(v_pages, block_table), 2, 1)
+        k = gather_kv_pages(k_pages, block_table)
+        v = gather_kv_pages(v_pages, block_table)
         if k_scale is not None:
-            ks = jnp.moveaxis(gather_pages(k_scale, block_table), 2, 1)
-            vs = jnp.moveaxis(gather_pages(v_scale, block_table), 2, 1)
+            ks = gather_kv_pages(k_scale, block_table)
+            vs = gather_kv_pages(v_scale, block_table)
             k = k.astype(jnp.float32) * ks.astype(jnp.float32)[..., None]
             v = v.astype(jnp.float32) * vs.astype(jnp.float32)[..., None]
         return fusemax_decode(
@@ -568,6 +589,7 @@ def fusemax_decode_paged(
         block_k -= 1
     block_k = autotune.verify_block_k(
         block_k, p=p, g=max(group, 8), e=e, f=f)
+    block_k = _scale_block_k(block_k, page_size, k_scale is not None)
 
     interpret = (not _on_tpu()) if interpret is None else interpret
     out = fusemax_decode_paged_pallas(
@@ -777,6 +799,7 @@ def fusemax_mla_decode_paged(
         block_k -= 1
     block_k = autotune.verify_block_k(
         block_k, p=p, g=max(hq, 8), e=rank + rope_dim, f=rank)
+    block_k = _scale_block_k(block_k, page_size, ckv_scale is not None)
 
     interpret = (not _on_tpu()) if interpret is None else interpret
     out = fusemax_mla_decode_paged_pallas(

@@ -4,35 +4,16 @@ Defined as FUNCTIONS (never module-level constants) so importing this
 module never touches jax device state.  The dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import to build these meshes on CPU.
-
-Version compat: ``jax.sharding.AxisType`` only exists on jax >= 0.6 —
-on older jax (0.4.x / 0.5.x) every mesh axis is implicitly "auto", so the
-plain ``jax.make_mesh(shape, axes)`` (or, where even that is missing, a
-``Mesh`` over ``mesh_utils.create_device_mesh``) is semantically
-identical.  ``_axis_type_kwargs`` centralizes the guard.
 """
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,) * n`` on jax >= 0.6, nothing on older jax
-    (where meshes are auto-typed and the kwarg does not exist)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+import numpy as np
 
 
 def _build_mesh(shape, axes):
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             **_axis_type_kwargs(len(axes)))
-    from jax.experimental import mesh_utils
-
-    devices = mesh_utils.create_device_mesh(tuple(shape))
-    return jax.sharding.Mesh(devices, tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -52,17 +33,12 @@ def make_replica_meshes(dp: int, tp: int = 1):
     replicas, each tensor-parallel over its own ``tp`` contiguous devices
     (see ``repro.distributed.sharding.replica_device_groups``).  Replicas
     never communicate — the async router fans requests out host-side — so
-    there is no global dp axis; ``tp == 1`` returns ``[None] * dp``
-    (unsharded engines, the CPU smoke path)."""
-    if tp <= 1:
-        if dp < 1:
-            raise ValueError(f"need dp >= 1, got {dp}")
-        return [None] * dp
+    there is no global dp axis.  With ``tp == 1`` each replica gets a
+    one-device mesh, which the engine reads as "place params and caches
+    on this device"."""
     from repro.distributed.sharding import replica_device_groups
-
-    import numpy as np
 
     groups = replica_device_groups(dp, tp)
     return [jax.sharding.Mesh(np.asarray(g), ("model",),
-                              **_axis_type_kwargs(1))
+                              axis_types=(jax.sharding.AxisType.Auto,))
             for g in groups]

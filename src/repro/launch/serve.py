@@ -327,7 +327,8 @@ def serve_async_bench(args) -> dict:
                          "(the fused verify dispatch conflicts with "
                          "mid-prefill slots)")
     cfg = get_config(args.arch)
-    rt = Runtime(activation_dtype=jnp.float32, param_dtype=jnp.float32)
+    rt = Runtime(attn_impl="auto", activation_dtype=jnp.float32,
+                 param_dtype=jnp.float32)
     params, _ = tf.init(cfg, jax.random.PRNGKey(args.seed), rt)
     prompts, budgets = _async_trace(args, cfg)
     lens = sorted({len(p) for p in prompts})
@@ -446,7 +447,8 @@ def serve_async_bench(args) -> dict:
 def serve_bench(args) -> dict:
     """Build engine(s), serve the synthetic trace, return the metrics."""
     cfg = get_config(args.arch)
-    rt = Runtime(activation_dtype=jnp.float32, param_dtype=jnp.float32)
+    rt = Runtime(attn_impl="auto", activation_dtype=jnp.float32,
+                 param_dtype=jnp.float32)
     params, _ = tf.init(cfg, jax.random.PRNGKey(args.seed), rt)
 
     layouts = ["dense", "paged"] if args.cache_layout == "both" \
@@ -645,8 +647,8 @@ def main(argv=None) -> dict:
                          "drafting gets real acceptance")
     ap.add_argument("--kv-dtype", default=None,
                     choices=("fp8_e4m3", "int8"),
-                    help="store paged K/V quantized (per-page fp32 scales "
-                         "in a parallel pool; kernels dequantize "
+                    help="store paged K/V quantized (per-token fp16 "
+                         "scales in a parallel pool; kernels apply them "
                          "in-register): adds a 'paged_quant' leg excluded "
                          "from outputs_match, with greedy-stream drift vs "
                          "the exact paged leg under 'quant_quality'")
@@ -705,15 +707,12 @@ def main(argv=None) -> dict:
                          "--arrival-rate)")
     ap.add_argument("--json", default="BENCH_serving.json",
                     help="write metrics here ('' to disable)")
-    ap.add_argument("--no-compile-cache", action="store_true",
-                    help="skip the persistent XLA compilation cache")
     ap.add_argument("--no-warmup", action="store_true",
                     help="skip the deploy-time engine warmup (cold-start "
                          "costs then land in the timed trace)")
     args = ap.parse_args(argv)
 
-    if not args.no_compile_cache:
-        enable_compilation_cache()
+    enable_compilation_cache()
     if args.run_async:
         if args.json == "BENCH_serving.json":
             args.json = "BENCH_serving_async.json"

@@ -17,7 +17,7 @@ Dense layout (one row per batch slot, ``max_len`` reserved up front):
 
 Paged layout (page pool + per-slot block table indirection — resident
 memory tracks live tokens, see :mod:`repro.serving.kv_cache`):
-  GQA  {"k_pages","v_pages": [P, page_size, Hkv, dh]}
+  GQA  {"k_pages","v_pages": [P, Hkv, page_size, dh]}   (head-major)
   MLA  {"ckv_pages": [P, page_size, r], "krope_pages": [P, page_size, rd]}
   Token at logical index l = position % capacity lives at
   (block_table[slot, l // page_size], l % page_size); ``capacity`` is
@@ -63,11 +63,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import LayerSpec, ModelConfig
-from repro.distributed.sharding import shard_map_fn
 from repro.kernels.ops import (
     fusemax_attention, fusemax_decode, fusemax_decode_paged,
-    fusemax_mla_decode_paged, gather_pages, mla_combine_partials,
-    mla_decode_partials,
+    fusemax_mla_decode_paged, gather_kv_pages, gather_pages,
+    mla_combine_partials, mla_decode_partials,
 )
 from repro.model.layers import (
     Runtime, _init, apply_norm, norm_init, rope,
@@ -94,13 +93,34 @@ def write_pages(pages: jnp.ndarray, bt_rows: jnp.ndarray,
     discards them, so padded bucket tails and unallocated sentinel entries
     never corrupt live pages.
     """
-    page_size = pages.shape[1]
+    page, slot = _page_slots(pages.shape[0], pages.shape[1], bt_rows,
+                             positions, capacity, valid)
+    return pages.at[page, slot].set(values.astype(pages.dtype), mode="drop")
+
+
+def write_kv_pages(pages: jnp.ndarray, bt_rows: jnp.ndarray,
+                   positions: jnp.ndarray, values: jnp.ndarray,
+                   capacity: int, valid: Optional[jnp.ndarray] = None
+                   ) -> jnp.ndarray:
+    """:func:`write_pages` for a head-major GQA pool: pages
+    [P, Hkv, page_size, *tail], values [N, Hkv, S, *tail] (the layout the
+    projections produce), same wrap and drop rules."""
+    page, slot = _page_slots(pages.shape[0], pages.shape[2], bt_rows,
+                             positions, capacity, valid)
+    heads = jnp.arange(pages.shape[1])[None, :, None]
+    return pages.at[page[:, None], heads, slot[:, None]].set(
+        values.astype(pages.dtype), mode="drop")
+
+
+def _page_slots(n_pages: int, page_size: int, bt_rows, positions,
+                capacity: int, valid):
+    """(page id, in-page slot) per token; ``valid``-False tokens get the
+    out-of-bounds page id ``n_pages`` so scatters drop them."""
     l = positions % capacity
     page = jnp.take_along_axis(bt_rows, l // page_size, axis=1)
     if valid is not None:
-        page = jnp.where(valid, page, pages.shape[0])    # OOB → dropped
-    return pages.at[page, l % page_size].set(
-        values.astype(pages.dtype), mode="drop")
+        page = jnp.where(valid, page, n_pages)           # OOB → dropped
+    return page, l % page_size
 
 
 # ---------------------------------------------------------------------------
@@ -108,7 +128,7 @@ def write_pages(pages: jnp.ndarray, bt_rows: jnp.ndarray,
 #
 # Quantized pools store pages in a narrow dtype (fp8_e4m3 / int8) plus a
 # parallel fp16 *scale pool* shaped like the data pages minus the trailing
-# feature axis — per-token-per-head for GQA ([P, ps, Hkv]), per-token for
+# feature axis — per-token-per-head for GQA ([P, Hkv, ps]), per-token for
 # MLA latents ([P, ps]).  Scales store as fp16 (values quantize against
 # the *rounded* scale, so the round-trip is still exact on representable
 # values; fp16's ~5e-4 relative scale error is dwarfed by fp8's ~4%
@@ -379,7 +399,7 @@ def gqa_decode(
 
 def gqa_init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                          dtype, kv_dtype: Optional[str] = None) -> dict:
-    shape = (num_pages, page_size, cfg.n_kv_heads, cfg.dh)
+    shape = (num_pages, cfg.n_kv_heads, page_size, cfg.dh)
     qdt = kv_quant_dtype(kv_dtype)
     if qdt is None:
         return {"k_pages": jnp.zeros(shape, dtype),
@@ -395,7 +415,7 @@ def _gqa_capacity(cache: dict, bt_rows: jnp.ndarray,
                   spec: LayerSpec) -> int:
     """Logical token capacity of a paged GQA cache: the window for local
     layers (ring eviction), the full table span for global layers."""
-    page_size = cache["k_pages"].shape[1]
+    page_size = cache["k_pages"].shape[2]
     return spec.window if spec.window is not None \
         else bt_rows.shape[1] * page_size
 
@@ -413,7 +433,7 @@ def _gqa_paged_attend(
     pre-output-projection attention output [B, H, S, F].
 
     Quantized pools pass their scale pools (``k_scale``/``v_scale``,
-    [P, ps, Hkv]); the gathered history is dequantized here, and the
+    [P, Hkv, ps]); the gathered history is dequantized here, and the
     caller supplies quant-round-tripped fresh K/V so the chunk attends
     exactly the values later reads will reconstruct.
 
@@ -433,19 +453,17 @@ def _gqa_paged_attend(
         )
     if spec.window is None:
         # gather only the pages the prefix occupies (off is static)
-        hp = -(-off // k_pages.shape[1])
-        k_hist = jnp.moveaxis(
-            gather_pages(k_pages, bt_rows[:, :hp]), 2, 1)[:, :, :off]
-        v_hist = jnp.moveaxis(
-            gather_pages(v_pages, bt_rows[:, :hp]), 2, 1)[:, :, :off]
+        hp = -(-off // k_pages.shape[2])
+        k_hist = gather_kv_pages(k_pages, bt_rows[:, :hp])[:, :, :off]
+        v_hist = gather_kv_pages(v_pages, bt_rows[:, :hp])[:, :, :off]
         if k_scale is not None:
             k_hist = dequantize_kv(
-                k_hist, jnp.moveaxis(
-                    gather_pages(k_scale, bt_rows[:, :hp]), 2, 1)[:, :, :off],
+                k_hist,
+                gather_kv_pages(k_scale, bt_rows[:, :hp])[:, :, :off],
                 k_new.dtype)
             v_hist = dequantize_kv(
-                v_hist, jnp.moveaxis(
-                    gather_pages(v_scale, bt_rows[:, :hp]), 2, 1)[:, :, :off],
+                v_hist,
+                gather_kv_pages(v_scale, bt_rows[:, :hp])[:, :, :off],
                 v_new.dtype)
         # chunk K/V rounded to the cache dtype first — the dense path reads
         # them back out of the cache it just wrote
@@ -462,17 +480,17 @@ def _gqa_paged_attend(
     w = spec.window
     klo = max(0, off - w + 1)
     l = jnp.arange(klo, off) % cap
-    page_size = k_pages.shape[1]
-    pg = bt_rows[:, l // page_size]                      # [B, band]
-    k_hist = jnp.moveaxis(k_pages[pg, l % page_size], 1, 2)
-    v_hist = jnp.moveaxis(v_pages[pg, l % page_size], 1, 2)
+    page_size = k_pages.shape[2]
+    pg = bt_rows[:, l // page_size][:, None]             # [B, 1, band]
+    heads = jnp.arange(k_pages.shape[1])[None, :, None]
+    slot = (l % page_size)[None, None]
+    k_hist = k_pages[pg, heads, slot]                    # [B, Hkv, band, dh]
+    v_hist = v_pages[pg, heads, slot]
     if k_scale is not None:
-        k_hist = dequantize_kv(
-            k_hist, jnp.moveaxis(k_scale[pg, l % page_size], 1, 2),
-            k_new.dtype)
-        v_hist = dequantize_kv(
-            v_hist, jnp.moveaxis(v_scale[pg, l % page_size], 1, 2),
-            v_new.dtype)
+        k_hist = dequantize_kv(k_hist, k_scale[pg, heads, slot],
+                               k_new.dtype)
+        v_hist = dequantize_kv(v_hist, v_scale[pg, heads, slot],
+                               v_new.dtype)
     return fusemax_attention(
         q, jnp.concatenate([k_hist, k_new], axis=2),
         jnp.concatenate([v_hist, v_new], axis=2),
@@ -538,12 +556,12 @@ def gqa_prefill_paged(
         q, k_new, v_new = _proj_qkv(p, x, cfg, positions, rt)
         k_q, k_s, v_q, v_s, k_att, v_att = _gqa_quant_new(cache, k_new,
                                                           v_new)
-        pspec = shard.spec(4, -2)                        # pages: Hkv axis
+        pspec = shard.spec(4, 1)                         # pages: Hkv axis
         hspec = shard.spec(4, 1)                         # [B, H*, S, E]
         rep = shard.replicated
 
         if k_s is not None:
-            sspec = shard.spec(3, -1)                    # scales: Hkv axis
+            sspec = shard.spec(3, 1)                     # scales: Hkv axis
             hspec3 = shard.spec(3, 1)                    # [B, Hkv, S]
 
             def local_q(kp, vp, ksp, vsp, q_l, ka_l, va_l, kq_l, vq_l,
@@ -551,20 +569,20 @@ def gqa_prefill_paged(
                 out = _gqa_paged_attend(q_l, ka_l, va_l, kp, vp, bt, off,
                                         cap, cfg, spec, rt,
                                         k_scale=ksp, v_scale=vsp)
-                kp = write_pages(kp, bt, pos_b, jnp.moveaxis(kq_l, 1, 2),
+                kp = write_kv_pages(kp, bt, pos_b, kq_l,
                                  cap, val)
-                vp = write_pages(vp, bt, pos_b, jnp.moveaxis(vq_l, 1, 2),
+                vp = write_kv_pages(vp, bt, pos_b, vq_l,
                                  cap, val)
-                ksp = write_pages(ksp, bt, pos_b, jnp.moveaxis(ks_l, 1, 2),
+                ksp = write_kv_pages(ksp, bt, pos_b, ks_l,
                                   cap, val)
-                vsp = write_pages(vsp, bt, pos_b, jnp.moveaxis(vs_l, 1, 2),
+                vsp = write_kv_pages(vsp, bt, pos_b, vs_l,
                                   cap, val)
                 out = jax.lax.all_gather(out, shard.axis, axis=1,
                                          tiled=True)
                 return out, kp, vp, ksp, vsp
 
-            out, k_pages, v_pages, k_sc, v_sc = shard_map_fn()(
-                local_q, mesh=shard.mesh,
+            out, k_pages, v_pages, k_sc, v_sc = jax.shard_map(
+                local_q, mesh=shard.mesh, check_vma=False,
                 in_specs=(pspec, pspec, sspec, sspec, hspec, hspec, hspec,
                           hspec, hspec, hspec3, hspec3, rep, rep, rep),
                 out_specs=(rep, pspec, pspec, sspec, sspec),
@@ -578,15 +596,15 @@ def gqa_prefill_paged(
         def local(kp, vp, q_l, kn_l, vn_l, bt, pos_b, val):
             out = _gqa_paged_attend(q_l, kn_l, vn_l, kp, vp, bt, off, cap,
                                     cfg, spec, rt)
-            kp = write_pages(kp, bt, pos_b, jnp.moveaxis(kn_l, 1, 2), cap,
+            kp = write_kv_pages(kp, bt, pos_b, kn_l, cap,
                              val)
-            vp = write_pages(vp, bt, pos_b, jnp.moveaxis(vn_l, 1, 2), cap,
+            vp = write_kv_pages(vp, bt, pos_b, vn_l, cap,
                              val)
             out = jax.lax.all_gather(out, shard.axis, axis=1, tiled=True)
             return out, kp, vp
 
-        out, k_pages, v_pages = shard_map_fn()(
-            local, mesh=shard.mesh,
+        out, k_pages, v_pages = jax.shard_map(
+            local, mesh=shard.mesh, check_vma=False,
             in_specs=(pspec, pspec, hspec, hspec, hspec, rep, rep, rep),
             out_specs=(rep, pspec, pspec),
         )(cache["k_pages"], cache["v_pages"], q, k_new, v_new, bt_rows,
@@ -609,18 +627,18 @@ def gqa_prefill_paged(
         y = jnp.einsum("bhse,hed->bsd", out, p["wo"].astype(x.dtype))
 
     new_cache = {
-        "k_pages": write_pages(cache["k_pages"], bt_rows, positions,
-                               jnp.moveaxis(k_q, 1, 2), cap, valid),
-        "v_pages": write_pages(cache["v_pages"], bt_rows, positions,
-                               jnp.moveaxis(v_q, 1, 2), cap, valid),
+        "k_pages": write_kv_pages(cache["k_pages"], bt_rows, positions,
+                               k_q, cap, valid),
+        "v_pages": write_kv_pages(cache["v_pages"], bt_rows, positions,
+                               v_q, cap, valid),
     }
     if k_s is not None:
-        new_cache["k_scale"] = write_pages(
+        new_cache["k_scale"] = write_kv_pages(
             cache["k_scale"], bt_rows, positions,
-            jnp.moveaxis(k_s, 1, 2), cap, valid)
-        new_cache["v_scale"] = write_pages(
+            k_s, cap, valid)
+        new_cache["v_scale"] = write_kv_pages(
             cache["v_scale"], bt_rows, positions,
-            jnp.moveaxis(v_s, 1, 2), cap, valid)
+            v_s, cap, valid)
     return y, new_cache
 
 
@@ -653,23 +671,23 @@ def gqa_decode_paged(
 
     shard = rt.kv_shard
     if shard is not None:
-        pspec = shard.spec(4, -2)
+        pspec = shard.spec(4, 1)
         hspec = shard.spec(4, 1)
         rep = shard.replicated
 
         if k_s is not None:
-            sspec = shard.spec(3, -1)
+            sspec = shard.spec(3, 1)
             hspec3 = shard.spec(3, 1)
 
             def local_q(kp, vp, ksp, vsp, q_l, kq_l, vq_l, ks_l, vs_l, bt,
                         pos_b, val, el):
-                kp = write_pages(kp, bt, pos_b, jnp.moveaxis(kq_l, 1, 2),
+                kp = write_kv_pages(kp, bt, pos_b, kq_l,
                                  cap, val)
-                vp = write_pages(vp, bt, pos_b, jnp.moveaxis(vq_l, 1, 2),
+                vp = write_kv_pages(vp, bt, pos_b, vq_l,
                                  cap, val)
-                ksp = write_pages(ksp, bt, pos_b, jnp.moveaxis(ks_l, 1, 2),
+                ksp = write_kv_pages(ksp, bt, pos_b, ks_l,
                                   cap, val)
-                vsp = write_pages(vsp, bt, pos_b, jnp.moveaxis(vs_l, 1, 2),
+                vsp = write_kv_pages(vsp, bt, pos_b, vs_l,
                                   cap, val)
                 out = fusemax_decode_paged(
                     q_l, kp, vp, bt, el,
@@ -682,8 +700,8 @@ def gqa_decode_paged(
                                          tiled=True)
                 return out, kp, vp, ksp, vsp
 
-            out, k_pages, v_pages, k_sc, v_sc = shard_map_fn()(
-                local_q, mesh=shard.mesh,
+            out, k_pages, v_pages, k_sc, v_sc = jax.shard_map(
+                local_q, mesh=shard.mesh, check_vma=False,
                 in_specs=(pspec, pspec, sspec, sspec, hspec, hspec, hspec,
                           hspec3, hspec3, rep, rep, rep, rep),
                 out_specs=(rep, pspec, pspec, sspec, sspec),
@@ -695,9 +713,9 @@ def gqa_decode_paged(
                        "k_scale": k_sc, "v_scale": v_sc}
 
         def local(kp, vp, q_l, kn_l, vn_l, bt, pos_b, val, el):
-            kp = write_pages(kp, bt, pos_b, jnp.moveaxis(kn_l, 1, 2), cap,
+            kp = write_kv_pages(kp, bt, pos_b, kn_l, cap,
                              val)
-            vp = write_pages(vp, bt, pos_b, jnp.moveaxis(vn_l, 1, 2), cap,
+            vp = write_kv_pages(vp, bt, pos_b, vn_l, cap,
                              val)
             out = fusemax_decode_paged(
                 q_l, kp, vp, bt, el,
@@ -708,8 +726,8 @@ def gqa_decode_paged(
             out = jax.lax.all_gather(out, shard.axis, axis=1, tiled=True)
             return out, kp, vp
 
-        out, k_pages, v_pages = shard_map_fn()(
-            local, mesh=shard.mesh,
+        out, k_pages, v_pages = jax.shard_map(
+            local, mesh=shard.mesh, check_vma=False,
             in_specs=(pspec, pspec, hspec, hspec, hspec, rep, rep, rep,
                       rep),
             out_specs=(rep, pspec, pspec),
@@ -719,17 +737,17 @@ def gqa_decode_paged(
         return y, {"k_pages": k_pages, "v_pages": v_pages}
 
     new_cache = {
-        "k_pages": write_pages(cache["k_pages"], bt_rows, pos,
-                               jnp.moveaxis(k_q, 1, 2), cap, valid),
-        "v_pages": write_pages(cache["v_pages"], bt_rows, pos,
-                               jnp.moveaxis(v_q, 1, 2), cap, valid),
+        "k_pages": write_kv_pages(cache["k_pages"], bt_rows, pos,
+                               k_q, cap, valid),
+        "v_pages": write_kv_pages(cache["v_pages"], bt_rows, pos,
+                               v_q, cap, valid),
     }
     if k_s is not None:
-        new_cache["k_scale"] = write_pages(
-            cache["k_scale"], bt_rows, pos, jnp.moveaxis(k_s, 1, 2), cap,
+        new_cache["k_scale"] = write_kv_pages(
+            cache["k_scale"], bt_rows, pos, k_s, cap,
             valid)
-        new_cache["v_scale"] = write_pages(
-            cache["v_scale"], bt_rows, pos, jnp.moveaxis(v_s, 1, 2), cap,
+        new_cache["v_scale"] = write_kv_pages(
+            cache["v_scale"], bt_rows, pos, v_s, cap,
             valid)
     out = fusemax_decode_paged(
         q, new_cache["k_pages"], new_cache["v_pages"], bt_rows, eff_len,
@@ -802,17 +820,17 @@ def gqa_verify_paged(
 
     k_q, k_s, v_q, v_s, _, _ = _gqa_quant_new(cache, k_new, v_new)
     new_cache = {
-        "k_pages": write_pages(cache["k_pages"], bt_rows, pos,
-                               jnp.moveaxis(k_q, 1, 2), cap, valid),
-        "v_pages": write_pages(cache["v_pages"], bt_rows, pos,
-                               jnp.moveaxis(v_q, 1, 2), cap, valid),
+        "k_pages": write_kv_pages(cache["k_pages"], bt_rows, pos,
+                               k_q, cap, valid),
+        "v_pages": write_kv_pages(cache["v_pages"], bt_rows, pos,
+                               v_q, cap, valid),
     }
     if k_s is not None:
-        new_cache["k_scale"] = write_pages(
-            cache["k_scale"], bt_rows, pos, jnp.moveaxis(k_s, 1, 2), cap,
+        new_cache["k_scale"] = write_kv_pages(
+            cache["k_scale"], bt_rows, pos, k_s, cap,
             valid)
-        new_cache["v_scale"] = write_pages(
-            cache["v_scale"], bt_rows, pos, jnp.moveaxis(v_s, 1, 2), cap,
+        new_cache["v_scale"] = write_kv_pages(
+            cache["v_scale"], bt_rows, pos, v_s, cap,
             valid)
     out = fusemax_decode_paged(
         q, new_cache["k_pages"], new_cache["v_pages"], bt_rows, kv_len,
@@ -1173,8 +1191,8 @@ def mla_prefill_paged(
         rep = shard.replicated
         outs = ((pspec, pspec) if off == 0
                 else (pspec, pspec, rep, rep))
-        got = shard_map_fn()(
-            local, mesh=shard.mesh,
+        got = jax.shard_map(
+            local, mesh=shard.mesh, check_vma=False,
             in_specs=(pspec, pspec, pspec, pspec, rep, rep, rep),
             out_specs=outs,
         )(cache["ckv_pages"], cache["krope_pages"], ckv_q, kr_q,
@@ -1293,8 +1311,8 @@ def mla_decode_paged(
         if scale_new:
             specs += [rep, rep]
             operands += [scale_new["ckv_scale"], scale_new["krope_scale"]]
-        out_lat, ckv_pages, krope_pages = shard_map_fn()(
-            local, mesh=shard.mesh,
+        out_lat, ckv_pages, krope_pages = jax.shard_map(
+            local, mesh=shard.mesh, check_vma=False,
             in_specs=tuple(specs),
             out_specs=(rep, pspec, pspec),
         )(*operands)

@@ -35,7 +35,8 @@ Axes = Any        # nested dict of tuples (mirrors Params)
 class Runtime:
     """Execution knobs threaded through forward passes (not config)."""
 
-    attn_impl: str = "jnp"      # "jnp" | "pallas" | "ref"
+    #: "jnp" | "pallas" | "ref" | "auto" (Pallas on TPU, jnp elsewhere)
+    attn_impl: str = "jnp"
     exp_impl: str = "native"    # "native" | "maccs"
     #: kernel tile sizes; None → per-(shape, backend) autotuner defaults
     #: (repro.kernels.autotune)

@@ -405,7 +405,7 @@ def layer_init_paged_cache(cfg: ModelConfig, spec: LayerSpec, slots: int,
     page pools (``num_pages`` keyed like the block tables — "full" /
     "w<window>"); SSM state stays per-slot dense (it is O(1) per slot).
     ``kv_dtype`` ("fp8_e4m3" | "int8" | None) stores the pools quantized
-    with parallel fp32 scale pools — see the attention init helpers."""
+    with parallel fp16 scale pools — see the attention init helpers."""
     cache = {}
     if spec.attn == "gqa":
         cache["attn"] = attn_mod.gqa_init_paged_cache(

@@ -46,7 +46,7 @@ The hot path is device-resident end-to-end:
     on-device early exit once every slot's budget is spent) samples,
     appends to the cache, and advances ``kv_len`` for up to
     ``decode_chunk`` tokens per dispatch; caches and per-slot state are
-    donated so no per-step copy survives (donation is a no-op on CPU).
+    donated so no per-step copy survives.
     For the paged layout the engine reserves every slot's worst-case page
     growth for the chunk *before* dispatching, so the block tables are
     loop-invariant on device.
@@ -87,6 +87,8 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import logging
+import os
+import pathlib
 import time
 from typing import Any, Callable, Iterable, Optional, Union
 
@@ -104,33 +106,21 @@ from repro.serving.kv_cache import PagedKVCache
 from repro.serving.speculate import NGramProposer
 
 
-def enable_compilation_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point jax at a persistent compilation cache so serving cold-starts
-    amortize XLA compiles across processes (standard deployment practice;
-    works on CPU/GPU/TPU backends).  Honors ``REPRO_JAX_CACHE_DIR``; set it
-    to "" to disable.  Returns the cache dir (or None if disabled)."""
-    import os
+def enable_compilation_cache() -> str:
+    """Keep jax's persistent compilation cache in one fixed place, so a
+    second process in the same checkout reuses the compiled programs.
 
-    if path is None:
-        # repo-local when running from a source checkout
-        # (…/src/repro/serving/engine.py → repo root); site installs land
-        # in a user cache dir instead of inside site-packages
-        root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))))
-        if os.path.isdir(os.path.join(root, ".git")):
-            default = os.path.join(root, ".jax_cache")
-        else:
-            default = os.path.join(
-                os.path.expanduser("~"), ".cache", "repro", "jax")
-        path = os.environ.get("REPRO_JAX_CACHE_DIR", default)
-    if not path:
-        return None
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        return None
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and
+    nothing is changed here.  Otherwise the cache goes to
+    ``<checkout>/.jax_cache``, found from this file's path.  Returns the
+    directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
 
 
@@ -289,6 +279,13 @@ class ServeEngine:
             # every non-paged op stays bit-identical to the 1-device path
             params = jax.device_put(params, NamedSharding(mesh, P()))
             rt = dataclasses.replace(rt, kv_shard=shard)
+        placement = contextlib.nullcontext()
+        if mesh is not None and mesh.size == 1:
+            # a one-device replica (dp serving): params, caches and the
+            # per-slot state all live on that device
+            device = mesh.devices.flat[0]
+            params = jax.device_put(params, device)
+            placement = jax.default_device(device)
         self.spec_k = None
         self.proposer = None
         if speculate is not None:
@@ -328,42 +325,44 @@ class ServeEngine:
             else max(1, prefill_chunk)
         self.cache_dtype = dtype
         self.cache_layout = cache_layout
-        if cache_layout == "paged":
-            self.kv = PagedKVCache(cfg, slots, max_len, dtype,
-                                   page_size=page_size,
-                                   num_pages=num_pages,
-                                   prefix_caching=prefix_caching,
-                                   kv_dtype=kv_dtype,
-                                   pool_bytes=pool_bytes,
-                                   host_swap_bytes=host_swap_bytes,
-                                   shard=shard)
-            self.caches = self.kv.caches
-            # the swap tier snapshots page contents at demotion time; hand
-            # it a live view of the engine's current cache pytree (COW and
-            # the decode loop rebind self.caches every dispatch)
-            self.kv.cache_source = lambda: self.caches
-            if shard is not None and any(
-                    s.attn == "mla" for s in cfg.layer_specs()):
-                w = self.kv.classes["full"].table_width
-                if w % shard.size:
-                    raise ValueError(
-                        f"MLA rank-sharded decode sweeps the block table "
-                        f"in contiguous per-device page strips, so the "
-                        f"table width {w} (= ceil(max_len/page_size)) "
-                        f"must divide by tp={shard.size} — adjust "
-                        f"max_len or page_size")
-        else:
-            self.kv = None
-            self.caches = tf.init_cache(cfg, slots, max_len, dtype)
-        # host mirrors of per-slot state (device copies live in _kv_len &c)
-        self.kv_len = np.zeros((slots,), np.int32)
-        self.remaining = np.zeros((slots,), np.int32)
-        self.active: list[Optional[Request]] = [None] * slots
-        self.queue: list[Request] = []
-        self.key = jax.random.PRNGKey(0)
-        self._kv_len = jnp.zeros((slots,), jnp.int32)
-        self._remaining = jnp.zeros((slots,), jnp.int32)
-        self._last_logits = jnp.zeros((slots, cfg.vocab), jnp.float32)
+        with placement:
+            if cache_layout == "paged":
+                self.kv = PagedKVCache(cfg, slots, max_len, dtype,
+                                       page_size=page_size,
+                                       num_pages=num_pages,
+                                       prefix_caching=prefix_caching,
+                                       kv_dtype=kv_dtype,
+                                       pool_bytes=pool_bytes,
+                                       host_swap_bytes=host_swap_bytes,
+                                       shard=shard)
+                self.caches = self.kv.caches
+                # the swap tier snapshots page contents at demotion time;
+                # hand it a live view of the engine's current cache pytree
+                # (COW and the decode loop rebind self.caches every
+                # dispatch)
+                self.kv.cache_source = lambda: self.caches
+                if shard is not None and any(
+                        s.attn == "mla" for s in cfg.layer_specs()):
+                    w = self.kv.classes["full"].table_width
+                    if w % shard.size:
+                        raise ValueError(
+                            f"MLA rank-sharded decode sweeps the block "
+                            f"table in contiguous per-device page strips, "
+                            f"so the table width {w} (= ceil(max_len/"
+                            f"page_size)) must divide by tp={shard.size} "
+                            f"— adjust max_len or page_size")
+            else:
+                self.kv = None
+                self.caches = tf.init_cache(cfg, slots, max_len, dtype)
+            # host mirrors of per-slot state (device copies in _kv_len &c)
+            self.kv_len = np.zeros((slots,), np.int32)
+            self.remaining = np.zeros((slots,), np.int32)
+            self.active: list[Optional[Request]] = [None] * slots
+            self.queue: list[Request] = []
+            self.key = jax.random.PRNGKey(0)
+            self._kv_len = jnp.zeros((slots,), jnp.int32)
+            self._remaining = jnp.zeros((slots,), jnp.int32)
+            self._last_logits = jnp.zeros((slots, cfg.vocab), jnp.float32)
         self._prefill_fns: dict[tuple, Callable] = {}
         self._loop_fns: dict[int, Callable] = {}
         self._spec_fns: dict[int, Callable] = {}
@@ -378,10 +377,6 @@ class ServeEngine:
                       "spec_accepted": 0}
 
     # -- jit caches ---------------------------------------------------------
-
-    def _donate(self, argnums):
-        # buffer donation is unimplemented on CPU and warns per call
-        return argnums if jax.default_backend() != "cpu" else ()
 
     def _bucket(self, s: int) -> int:
         """Pad prompt lengths to power-of-two buckets (capped at max_len)
@@ -437,7 +432,7 @@ class ServeEngine:
                 return last_logits, caches
 
             fn = jax.jit(prefill_into_slots,
-                         donate_argnums=self._donate((2, 7)))
+                         donate_argnums=(2, 7))
         else:
             def prefill_into_slots(params, tokens, caches, slot_ids,
                                    true_len, last_logits):
@@ -453,7 +448,7 @@ class ServeEngine:
                 return last_logits, caches
 
             fn = jax.jit(prefill_into_slots,
-                         donate_argnums=self._donate((2, 5)))
+                         donate_argnums=(2, 5))
         self._prefill_fns[(n, s, off0)] = fn
         return fn
 
@@ -471,7 +466,7 @@ class ServeEngine:
                     block_tables=tables)
         else:
             loop = make_decode_loop(cfg, n_steps, rt, temperature)
-        fn = jax.jit(loop, donate_argnums=self._donate((1, 2, 3, 4, 5)))
+        fn = jax.jit(loop, donate_argnums=(1, 2, 3, 4, 5))
         self._loop_fns[n_steps] = fn
         return fn
 
@@ -495,7 +490,7 @@ class ServeEngine:
                 return tf.speculative_step(
                     cfg, params, last_logits, drafts, caches, kv_len,
                     remaining, rt)
-        fn = jax.jit(spec, donate_argnums=self._donate((1, 3, 4, 5)))
+        fn = jax.jit(spec, donate_argnums=(1, 3, 4, 5))
         self._spec_fns[p_total] = fn
         return fn
 

@@ -6,7 +6,7 @@ resident bytes track *live tokens* instead — the off-chip analogue of the
 paper's M-independent on-chip buffering:
 
     layer storage (device, one per layer)     block table (host-mirrored,
-    [num_pages, page_size, Hkv, dh]           one per capacity class,
+    [num_pages, Hkv, page_size, dh]           one per capacity class,
                                               shared by all its layers)
     ┌────────┐                                 slot 0: [ 3, 7, 1, ·]
     │ page 0 │◄───────┐                        slot 1: [ 0, 4, ·, ·]
@@ -854,13 +854,12 @@ class PagedKVCache:
 
     def _cow_fn(self, key: str):
         """Jit'd ``pages[dst] = pages[src]`` over every layer of a class,
-        with the cache tree donated (off CPU) so the copy updates the pool
+        with the cache tree donated so the copy updates the pool
         in place instead of materializing a second full allocation per
         layer.  ``src``/``dst`` are device operands — one executable
         serves every COW of the class."""
         fn = self._cow_fns.get(key)
         if fn is None:
-            donate = (0,) if jax.default_backend() != "cpu" else ()
 
             def run(caches, src, dst):
                 out = tf.copy_cache_pages(self.cfg, caches, key, src, dst)
@@ -873,7 +872,7 @@ class PagedKVCache:
                         self._shardings)
                 return out
 
-            fn = jax.jit(run, donate_argnums=donate)
+            fn = jax.jit(run, donate_argnums=(0,))
             self._cow_fns[key] = fn
         return fn
 
@@ -894,7 +893,6 @@ class PagedKVCache:
         promotion is an in-place page DMA, not a pool reallocation."""
         fn = self._promote_jit
         if fn is None:
-            donate = (0,) if jax.default_backend() != "cpu" else ()
 
             def run(caches, blobs, dst):
                 i = 0
@@ -926,7 +924,7 @@ class PagedKVCache:
                         self._shardings)
                 return out
 
-            fn = jax.jit(run, donate_argnums=donate)
+            fn = jax.jit(run, donate_argnums=(0,))
             self._promote_jit = fn
         return fn
 

@@ -78,3 +78,21 @@ def test_disk_cache_roundtrip(tmp_path, monkeypatch):
     autotune.clear_table()
     hit = autotune.decode_params(512, 8, 32, 32)
     assert (hit.splits, hit.block_k) == (2, 256)
+
+
+def test_measure_best_names_skipped_candidate(caplog):
+    """An infeasible candidate is skipped, and the warning says which one
+    and why."""
+    autotune.clear_table()
+    good, bad = autotune.DecodeParams(1, 128), autotune.DecodeParams(2, 64)
+
+    def make_fn(cand):
+        if cand == bad:
+            raise ValueError("block does not fit")
+        return lambda: None
+
+    with caplog.at_level("WARNING", logger="repro.kernels.autotune"):
+        best, timings = autotune.measure_best(make_fn, [bad, good],
+                                              iters=1, warmup=0)
+    assert best == good and timings[bad] == float("inf")
+    assert repr(bad) in caplog.text and "block does not fit" in caplog.text

@@ -188,3 +188,53 @@ def test_serve_step_sharded_matches_reference():
         print("SERVE-OK")
     """)
     assert "SERVE-OK" in out
+
+
+def test_replica_meshes_place_each_replica_on_its_own_device():
+    """``make_replica_meshes(dp, 1)`` gives every replica its own device,
+    and an engine built on such a one-device mesh keeps its params and
+    cache pool there and serves from it."""
+    out = run_sub("""
+        import numpy as np, jax, jax.numpy as jnp
+        from repro.configs import get_config
+        from repro.launch.mesh import make_replica_meshes
+        from repro.model import transformer as tf
+        from repro.model.layers import Runtime
+        from repro.serving.engine import Request, ServeEngine
+
+        meshes = make_replica_meshes(4, 1)
+        devs = [m.devices.flat[0] for m in meshes]
+        assert [m.size for m in meshes] == [1] * 4
+        assert len(set(devs)) == 4, devs
+        cfg = get_config("stablelm-1.6b-smoke")
+        rt = Runtime(activation_dtype=jnp.float32, param_dtype=jnp.float32)
+        params, _ = tf.init(cfg, jax.random.PRNGKey(0), rt)
+        eng = ServeEngine(cfg, params, slots=2, max_len=32, rt=rt,
+                          decode_chunk=4, cache_layout="paged",
+                          page_size=8, mesh=meshes[2])
+        req = Request(rid=0, prompt=np.arange(5, dtype=np.int32),
+                      max_new_tokens=3)
+        eng.submit(req)
+        eng.run()
+        assert req.done and len(req.generated) == 3
+        for tree in (eng.params, eng.caches):
+            for leaf in jax.tree.leaves(tree):
+                assert leaf.devices() == {devs[2]}, leaf.devices()
+        print("REPLICAS-OK")
+    """, devices=4)
+    assert "REPLICAS-OK" in out
+
+
+@pytest.mark.parametrize("platform,dp,tp", [("tpu", 4, 1), ("cpu", 2, 2)])
+def test_replica_groups_refuse_to_stack_on_shared_devices(platform, dp, tp):
+    """Too few accelerator devices for ``dp`` replicas raises instead of
+    stacking replicas on one chip; only tp=1 on CPU devices may share."""
+    from types import SimpleNamespace
+
+    from repro.distributed.sharding import replica_device_groups
+
+    devs = [SimpleNamespace(platform=platform, id=i) for i in range(2)]
+    with pytest.raises(ValueError, match="needs"):
+        replica_device_groups(dp, tp, devices=devs)
+    cpu = [SimpleNamespace(platform="cpu", id=0)]
+    assert replica_device_groups(3, 1, devices=cpu) == [cpu] * 3

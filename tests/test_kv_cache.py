@@ -8,7 +8,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels import (
-    decode_reference, fusemax_decode_paged, gather_pages,
+    decode_reference, fusemax_decode_paged, gather_kv_pages,
 )
 from repro.model import transformer as tf
 from repro.model.layers import Runtime
@@ -172,13 +172,13 @@ def test_paged_pallas_decode_matches_reference():
     n_pages, ps, width = 10, 8, 4
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(ks[0], (b, hq, 1, e), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (n_pages, ps, hkv, e), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (n_pages, ps, hkv, f), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (n_pages, hkv, ps, e), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (n_pages, hkv, ps, f), jnp.float32)
     bt = jnp.asarray([[3, 1, 7, 0], [2, 5, 9, 4]], jnp.int32)
     kv_len = jnp.asarray([13, 29], jnp.int32)
 
-    k = jnp.moveaxis(gather_pages(k_pages, bt), 2, 1)
-    v = jnp.moveaxis(gather_pages(v_pages, bt), 2, 1)
+    k = gather_kv_pages(k_pages, bt)
+    v = gather_kv_pages(v_pages, bt)
     ref = decode_reference(q, k, v, kv_len)
     for impl in ("jnp", "pallas"):
         out = fusemax_decode_paged(q, k_pages, v_pages, bt, kv_len,
@@ -193,13 +193,13 @@ def test_paged_pallas_decode_splits_and_softcap():
     n_pages, ps, width = 12, 16, 8
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(ks[0], (b, hq, 1, e), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (n_pages, ps, hkv, e), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (n_pages, ps, hkv, e), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (n_pages, hkv, ps, e), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (n_pages, hkv, ps, e), jnp.float32)
     bt = jax.random.permutation(ks[3], n_pages)[:width][None].astype(
         jnp.int32)
     kv_len = jnp.asarray([77], jnp.int32)
-    k = jnp.moveaxis(gather_pages(k_pages, bt), 2, 1)
-    v = jnp.moveaxis(gather_pages(v_pages, bt), 2, 1)
+    k = gather_kv_pages(k_pages, bt)
+    v = gather_kv_pages(v_pages, bt)
     ref = decode_reference(q, k, v, kv_len, softcap=30.0)
     out = fusemax_decode_paged(q, k_pages, v_pages, bt, kv_len,
                                softcap=30.0, impl="pallas", splits=4,

@@ -88,27 +88,27 @@ def test_quant_roundtrip_within_dtype_step(kv_dtype, step):
 
 def test_quant_page_write_gather_parity():
     """Through the real page machinery: quantize fresh K, scatter data
-    and scales into their pools with ``write_pages``, gather through a
-    block table, dequantize — matches the direct round-trip bitwise."""
-    from repro.kernels.ops import gather_pages
-    from repro.model.attention import write_pages
+    and scales into their pools with ``write_kv_pages``, gather through
+    a block table, dequantize — matches the direct round-trip bitwise."""
+    from repro.kernels.ops import gather_kv_pages
+    from repro.model.attention import write_kv_pages
 
     cfg = get_config("stablelm-1.6b-smoke")
     cache = gqa_init_paged_cache(cfg, num_pages=6, page_size=8,
                                  dtype=jnp.float32, kv_dtype="fp8_e4m3")
     rng = np.random.default_rng(1)
-    k_new = jnp.asarray(              # [B=1, S=16, Hkv, dh]
-        rng.normal(size=(1, 16, cfg.n_kv_heads, cfg.dh))
+    k_new = jnp.asarray(              # [B=1, Hkv, S=16, dh]
+        rng.normal(size=(1, cfg.n_kv_heads, 16, cfg.dh))
         .astype(np.float32))
     q, s = quantize_kv(k_new, cache["k_pages"].dtype)
     bt = jnp.asarray([[2, 4]], jnp.int32)
     pos = jnp.arange(16, dtype=jnp.int32)[None]
-    pages = write_pages(cache["k_pages"], bt, pos, q, 64,
-                        jnp.asarray([16], jnp.int32))
-    scales = write_pages(cache["k_scale"], bt, pos, s, 64,
-                         jnp.asarray([16], jnp.int32))
-    got = dequantize_kv(gather_pages(pages, bt)[:, :16],
-                        gather_pages(scales, bt)[:, :16])
+    pages = write_kv_pages(cache["k_pages"], bt, pos, q, 64,
+                           jnp.asarray([16], jnp.int32))
+    scales = write_kv_pages(cache["k_scale"], bt, pos, s, 64,
+                            jnp.asarray([16], jnp.int32))
+    got = dequantize_kv(gather_kv_pages(pages, bt)[:, :, :16],
+                        gather_kv_pages(scales, bt)[:, :, :16])
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(dequantize_kv(q, s)))
 

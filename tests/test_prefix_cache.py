@@ -2,8 +2,7 @@
 admission is greedy-bit-identical to cold prefill (dense vs paged vs
 paged+prefix), COW isolates divergent continuations from the shared
 pages, refcounts never go negative and the pool drains once the index is
-dropped, double frees raise, and the jax-version mesh fallback works with
-and without ``jax.sharding.AxisType``."""
+dropped, and double frees raise."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -100,7 +99,7 @@ def test_cow_isolation_on_divergence():
     eng.run()
     donor = {h: e.page for h, e in eng.kv._prefix.items()}
     assert len(donor) >= 2                  # both prompt pages indexed
-    # stacked-run leaf: [reps, P, page_size, Hkv, dh] — page axis is 1
+    # stacked-run leaf: [reps, P, Hkv, page_size, dh] — page axis is 1
     leaf = np.asarray(eng.caches[0][0]["attn"]["k_pages"])
     snap = {p: leaf[:, p].copy() for p in donor.values()}
 
@@ -316,20 +315,20 @@ def test_paged_decode_sentinel_rows_safe():
     must not perturb other slots, on the jnp path and the Pallas kernel
     (reads clamp in the index_map, scores are masked by kv_len)."""
     from repro.kernels import (
-        decode_reference, fusemax_decode_paged, gather_pages,
+        decode_reference, fusemax_decode_paged, gather_kv_pages,
     )
     b, hq, hkv, e, f = 2, 4, 2, 16, 16
     n_pages, ps, width = 10, 8, 4
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(ks[0], (b, hq, 1, e), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (n_pages, ps, hkv, e), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (n_pages, ps, hkv, f), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (n_pages, hkv, ps, e), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (n_pages, hkv, ps, f), jnp.float32)
     sentinel = n_pages
     bt = jnp.asarray([[3, 1, 7, sentinel],
                       [sentinel] * width], jnp.int32)      # slot 1 released
     kv_len = jnp.asarray([21, 0], jnp.int32)
-    k = jnp.moveaxis(gather_pages(k_pages, bt[:1]), 2, 1)
-    v = jnp.moveaxis(gather_pages(v_pages, bt[:1]), 2, 1)
+    k = gather_kv_pages(k_pages, bt[:1])
+    v = gather_kv_pages(v_pages, bt[:1])
     ref = decode_reference(q[:1], k, v, kv_len[:1])
     for impl in ("jnp", "pallas"):
         out = fusemax_decode_paged(q, k_pages, v_pages, bt, kv_len,
@@ -339,28 +338,3 @@ def test_paged_decode_sentinel_rows_safe():
                                    err_msg=f"impl={impl}")
         assert np.all(np.isfinite(np.asarray(out)))
 
-
-# ---------------------------------------------------------------------------
-# mesh fallback (jax-version compat)
-# ---------------------------------------------------------------------------
-
-def test_make_mesh_with_and_without_axis_type(monkeypatch):
-    """`launch.mesh` must build meshes whether or not the running jax
-    exposes ``jax.sharding.AxisType`` (added in jax 0.6)."""
-    from repro.launch import mesh as mesh_mod
-
-    # whatever this jax version is, a 1-device mesh must build
-    m = mesh_mod.make_mesh((1, 1), ("data", "model"))
-    assert tuple(m.axis_names) == ("data", "model")
-
-    # guard unit: absent → no kwarg; present → axis_types tuple
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    assert mesh_mod._axis_type_kwargs(2) == {}
-
-    class FakeAxisType:
-        Auto = "auto"
-
-    monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType,
-                        raising=False)
-    assert mesh_mod._axis_type_kwargs(3) == {
-        "axis_types": ("auto", "auto", "auto")}

@@ -219,3 +219,31 @@ def test_chunked_prefill_matches_whole_prompt():
             assert req.done
             outs.append(req.generated)
         assert outs[0] == outs[1], f"arch={arch}: {outs}"
+
+
+def test_compilation_cache_dir_rule(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and is left to jax; unset, the
+    cache sits at ``<checkout>/.jax_cache`` whether or not the checkout is
+    a git repository."""
+    import os
+
+    from repro.serving.engine import enable_compilation_cache
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compilation_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == \
+            saved["jax_compilation_cache_dir"]
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert enable_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)

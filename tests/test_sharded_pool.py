@@ -104,7 +104,7 @@ def test_sharded_gqa_matches_unsharded_with_prefix_and_cow():
         assert m0["peak_resident_cache_bytes"] == \\
             m1["peak_resident_cache_bytes"]
         # the physical page shard on device 0 really is 1/4 of the array
-        leaf = e1.kv.caches[0][0]["attn"]["k_pages"]
+        leaf = e1.caches[0][0]["attn"]["k_pages"]
         local = leaf.addressable_shards[0].data
         assert local.size * 4 == leaf.size, (local.shape, leaf.shape)
         print("GQA-SHARDED-OK", e1.stats["cow_copies"])
@@ -229,7 +229,7 @@ def test_sharded_swap_tier_matches_unsharded():
             assert e.kv.stats["promotions"] >= 3, e.kv.stats
         assert e0.kv.stats == e1.kv.stats, (e0.kv.stats, e1.kv.stats)
         # promoted page leaves keep the pool's sharding
-        leaf = e1.kv.caches[0][0]["attn"]["k_pages"]
+        leaf = e1.caches[0][0]["attn"]["k_pages"]
         local = leaf.addressable_shards[0].data
         assert local.size * 2 == leaf.size, (local.shape, leaf.shape)
         print("SWAP-SHARDED-OK", e1.kv.stats["promotions"])
