@@ -1,0 +1,16 @@
+"""Model step, decode: operations the decoded tokens of the traced window
+need (``bench/work.py``), over the decode programs' device time times the
+chip's peak bf16 rate, in %.  The whole decode step's share of the peak,
+beside the paged kernel's roofline.  Moves ``tpot_p95_ms``."""
+
+from bench import work
+
+
+def read(rec):
+    t = rec["trace"].programs.get("decode")
+    ctx = rec["decode_ctx"]
+    if not t or not ctx:
+        return None
+    s = rec["shapes"]
+    flops = sum(work.decode_token_flops(s, c) for c in ctx)
+    return 100.0 * flops / (t * rec["peak"]["bf16_flops_per_s"])
