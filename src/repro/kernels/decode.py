@@ -499,6 +499,7 @@ def fusemax_decode_paged_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="fusemax_decode_paged",
         interpret=interpret,
     )(kv_len.astype(jnp.int32), block_table.astype(jnp.int32),
       *operands)
@@ -873,6 +874,7 @@ def fusemax_mla_decode_paged_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="fusemax_mla_decode_paged",
         interpret=interpret,
     )(kv_len.astype(jnp.int32), block_table.astype(jnp.int32),
       *operands)

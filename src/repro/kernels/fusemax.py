@@ -253,5 +253,6 @@ def fusemax_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="fusemax_attention",
         interpret=interpret,
     )(q, k, v)

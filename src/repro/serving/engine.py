@@ -105,6 +105,12 @@ from repro.model.layers import Runtime
 from repro.serving.kv_cache import PagedKVCache
 from repro.serving.speculate import NGramProposer
 
+#: host phases of a scheduler turn: each is a profiler span
+#: ``engine.<phase>`` and a counter ``stats["host_ns_<phase>"]`` (dots
+#: as underscores) of the nanoseconds spent in it
+HOST_PHASES = ("pump", "admit", "prefill", "decode.prepare",
+               "decode.launch", "decode.wait", "decode.harvest")
+
 
 def enable_compilation_cache() -> str:
     """Keep jax's persistent compilation cache in one fixed place, so a
@@ -224,6 +230,7 @@ class Request:
     generated: list = dataclasses.field(default_factory=list)
     done: bool = False
     ttft: Optional[float] = None       # seconds, submit → first token known
+                                       # (synchronous ``submit`` only)
     preemptions: int = 0               # times bounced back to the queue
 
 
@@ -234,8 +241,10 @@ class ServeEngine:
     jit'd device programs: bucket-batched prefill and the fused multi-step
     decode loop.  ``stats`` counts device dispatches so callers can assert
     the dispatch economics (prefill dispatches independent of prompt
-    length; decode dispatches ≈ tokens / decode_chunk); ``memory_stats``
-    reports cache residency for the layout A/B.
+    length; decode dispatches ≈ tokens / decode_chunk), and its
+    ``host_ns_*`` counters time each host phase (``HOST_PHASES``), each
+    also a profiler span ``engine.<phase>``; ``memory_stats`` reports cache
+    residency for the layout A/B.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, slots: int,
@@ -374,7 +383,24 @@ class ServeEngine:
                       "prefix_hits": 0, "tokens_reused": 0,
                       "cow_copies": 0, "tokens_prefilled": 0,
                       "spec_dispatches": 0, "spec_proposed": 0,
-                      "spec_accepted": 0}
+                      "spec_accepted": 0,
+                      **{"host_ns_" + p.replace(".", "_"): 0
+                         for p in HOST_PHASES}}
+
+    @contextlib.contextmanager
+    def _span(self, phase: str, **ids):
+        """One host phase (``HOST_PHASES``): the profiler span
+        ``engine.<phase>`` with ``ids`` as its stats, on the device trace's
+        clock (inactive and nearly free when no profiler runs), and its
+        duration added to ``stats["host_ns_<phase>"]``, so a run with no
+        profiler still sees where the host spent its time."""
+        t0 = time.perf_counter_ns()
+        try:
+            with jax.profiler.TraceAnnotation(f"engine.{phase}", **ids):
+                yield
+        finally:
+            self.stats["host_ns_" + phase.replace(".", "_")] += \
+                time.perf_counter_ns() - t0
 
     # -- jit caches ---------------------------------------------------------
 
@@ -771,50 +797,55 @@ class ServeEngine:
             # full-chunk n costs nothing when fewer steps are needed; two
             # jit keys total {1, decode_chunk} — both built by warmup()
             n = self.decode_chunk
-        self._ensure_pages(n)          # may preempt → recompute the batch
-        act = [i for i, r in enumerate(self.active) if r is not None]
-        if not act:
-            return
-        rem_before = self.remaining.copy()
-        self._kv_len = jnp.asarray(self.kv_len)
-        self._remaining = jnp.asarray(self.remaining)
-        fn = self._get_loop(n)
-        args = (self.params, self.caches, self._kv_len, self._last_logits,
-                self._remaining, self.key)
-        if self.kv is not None:
-            args = args + (self.kv.tables(),)
-        toks, self.caches, self._kv_len, self._last_logits, \
-            self._remaining, self.key, steps = fn(*args)
-        self.stats["decode_dispatches"] += 1
-        self.stats["decode_steps"] += int(steps)
-
-        toks = np.asarray(toks)                       # [n, slots]; one sync
-        now = time.perf_counter()
-        self.kv_len = np.array(self._kv_len)          # writable host mirrors
-        self.remaining = np.array(self._remaining)
-        self._sync_live_peak()
-        for i in act:
-            req = self.active[i]
-            take = int(min(n, rem_before[i]))
-            if take > 0:
-                if not req.generated and req.ttft is None:
-                    req.ttft = now - getattr(req, "_t_submit", now)
-                got = [int(t) for t in toks[:take, i]]
-                req.generated.extend(got)
-                self.stats["tokens_decoded"] += take
-                if self.proposer is not None:
-                    self.proposer.extend(req.rid, got)
-            if self.remaining[i] <= 0:
-                req.done = True
-                self.active[i] = None
-                self.kv_len[i] = 0
-                if self.proposer is not None:
-                    self.proposer.finish(req.rid)
-                if self.kv is not None:
-                    # completion path: hand the slot's full token stream to
-                    # release so its full pages are demoted into the
-                    # reusable-prefix index instead of freed
-                    self.kv.release(i, tokens=self._resume_tokens(req))
+        with self._span("decode.prepare"):
+            self._ensure_pages(n)      # may preempt → recompute the batch
+            act = [i for i, r in enumerate(self.active) if r is not None]
+            if not act:
+                return
+            rem_before = self.remaining.copy()
+            self._kv_len = jnp.asarray(self.kv_len)
+            self._remaining = jnp.asarray(self.remaining)
+            fn = self._get_loop(n)
+            args = (self.params, self.caches, self._kv_len,
+                    self._last_logits, self._remaining, self.key)
+            if self.kv is not None:
+                args = args + (self.kv.tables(),)
+        with self._span("decode.launch"):      # enqueues; a compile shows
+            toks, self.caches, self._kv_len, self._last_logits, \
+                self._remaining, self.key, steps = fn(*args)
+        with self._span("decode.wait"):        # the host blocked on the chip
+            steps = int(steps)
+            toks = np.asarray(toks)                   # [n, slots]; one sync
+            self.kv_len = np.array(self._kv_len)      # writable host mirrors
+            self.remaining = np.array(self._remaining)
+        with self._span("decode.harvest"):
+            self.stats["decode_dispatches"] += 1
+            self.stats["decode_steps"] += steps
+            now = time.perf_counter()
+            self._sync_live_peak()
+            for i in act:
+                req = self.active[i]
+                take = int(min(n, rem_before[i]))
+                if take > 0:
+                    if not req.generated and req.ttft is None and \
+                            hasattr(req, "_t_submit"):  # sync submit only
+                        req.ttft = now - req._t_submit
+                    got = [int(t) for t in toks[:take, i]]
+                    req.generated.extend(got)
+                    self.stats["tokens_decoded"] += take
+                    if self.proposer is not None:
+                        self.proposer.extend(req.rid, got)
+                if self.remaining[i] <= 0:
+                    req.done = True
+                    self.active[i] = None
+                    self.kv_len[i] = 0
+                    if self.proposer is not None:
+                        self.proposer.finish(req.rid)
+                    if self.kv is not None:
+                        # completion path: hand the slot's full token
+                        # stream to release so its full pages are demoted
+                        # into the reusable-prefix index instead of freed
+                        self.kv.release(i, tokens=self._resume_tokens(req))
 
     def _spec_step(self, act: list) -> bool:
         """One fused speculate→verify→accept dispatch: score a k+1-token

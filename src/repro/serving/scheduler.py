@@ -142,13 +142,15 @@ def poisson_arrivals(rate: float, n: int, seed: int = 0,
 @dataclasses.dataclass
 class AsyncRequest(Request):
     """A :class:`Request` with an arrival time, an optional SLA deadline
-    (absolute clock time — sheddable until admitted), and per-token
+    (absolute clock time — sheddable until admitted), per-token
     timestamps (``token_times[i]`` is when ``generated[i]`` reached the
-    host)."""
+    host), and the engine-clock time of its first admission, kept across a
+    preemption (its queue wait is ``admitted_at - arrival``)."""
     arrival: float = 0.0
     deadline: Optional[float] = None
     token_times: list = dataclasses.field(default_factory=list)
     shed: bool = False
+    admitted_at: Optional[float] = None
 
 
 class TokenStream:
@@ -412,7 +414,6 @@ class AsyncServeEngine(ServeEngine):
                 f"free cache slot for decode (max_len={self.max_len})")
         if self.kv is not None:
             self.kv.validate_request(len(req.prompt) + req.max_new_tokens)
-        req._t_submit = time.perf_counter()
         self._reqs[req.rid] = req
         s = stream if stream is not None else TokenStream(req, self._drive)
         self._streams[req.rid] = s
@@ -426,26 +427,33 @@ class AsyncServeEngine(ServeEngine):
     def pump(self) -> bool:
         """One scheduler turn: shed expired, admit arrivals, execute one
         dispatch action.  Returns True if anything happened (False →
-        nothing runnable right now; see :meth:`_drive`)."""
-        now = self.clock.now()
-        did = False
-        for rid in self.sched.admissible(now):
-            if not self._admit_async(self._reqs[rid]):
-                break                      # no slot / pages: HOL waits
-            did = True
-        for rid in self.sched.take_shed():
-            req = self._reqs[rid]
-            req.shed = req.done = True
-            self._close_stream(rid)
-            did = True
-        action = self.sched.next_action(now)
-        if action[0] == "prefill":
-            self._prefill_quantum_dispatch(action[1])
-            return True
-        if action[0] == "decode":
-            self._decode_tick()
-            return True
-        return did
+        nothing runnable right now; see :meth:`_drive`).  The turn is the
+        host span ``engine.pump``; its own time, outside the child spans,
+        is the scheduling policy."""
+        with self._span("pump"):
+            now = self.clock.now()
+            did = False
+            for rid in self.sched.admissible(now):
+                req = self._reqs[rid]
+                with self._span("admit", rid=rid):
+                    if not self._admit_async(req):
+                        break              # no slot / pages: HOL waits
+                    if req.admitted_at is None:    # kept across preemption
+                        req.admitted_at = self.clock.now()
+                did = True
+            for rid in self.sched.take_shed():
+                req = self._reqs[rid]
+                req.shed = req.done = True
+                self._close_stream(rid)
+                did = True
+            action = self.sched.next_action(now)
+            if action[0] == "prefill":
+                self._prefill_quantum_dispatch(action[1])
+                return True
+            if action[0] == "decode":
+                self._decode_tick()
+                return True
+            return did
 
     def _drive(self) -> bool:
         """Advance the world by one event: pump, or jump the clock to the
@@ -538,58 +546,60 @@ class AsyncServeEngine(ServeEngine):
         (group width 1; jit key (1, quantum bucket, progress))."""
         slot = self._slot_of[rid]
         st = self._mid[slot]
-        self._flush_promotes()
-        if st.cow:
-            self.caches = self.kv.apply_cow(self.caches, st.cow)
-            st.cow = []
         L = len(st.tokens)
         off0 = st.progress
         c = min(self.prefill_quantum, L - off0)
-        sb = self._bucket(c)
-        toks = np.zeros((1, sb), np.int32)
-        toks[0, :c] = st.tokens[off0:off0 + c]
-        fn = self._get_prefill(1, sb, off0)
-        self._last_logits, self.caches = fn(
-            self.params, jnp.asarray(toks), self.caches,
-            self.kv.tables(),
-            jnp.asarray(np.array([slot], np.int32)),
-            jnp.asarray(np.array([L], np.int32)),
-            jnp.asarray(np.array([st.cached], np.int32)),
-            self._last_logits)
-        self.stats["prefill_dispatches"] += 1
-        self.stats["tokens_prefilled"] += c
-        st.progress += c
-        # pages fully written by this quantum become matchable now —
-        # their writing dispatch is in the device stream
-        self.kv.register_progress(slot, st.tokens, st.progress)
-        done = self.sched.advance(rid, c)
-        if done:
-            req = st.req
-            del self._mid[slot]
-            self.active[slot] = req
-            self.kv_len[slot] = L
-            budget = req.max_new_tokens - len(req.generated)
-            self.remaining[slot] = min(budget,
-                                       max(1, self.max_len - 1 - L))
-        else:
-            self.kv_len[slot] = st.progress + 1
-        self._sync_live_peak()
+        with self._span("prefill", rid=rid, start=off0, end=off0 + c):
+            self._flush_promotes()
+            if st.cow:
+                self.caches = self.kv.apply_cow(self.caches, st.cow)
+                st.cow = []
+            sb = self._bucket(c)
+            toks = np.zeros((1, sb), np.int32)
+            toks[0, :c] = st.tokens[off0:off0 + c]
+            fn = self._get_prefill(1, sb, off0)
+            self._last_logits, self.caches = fn(
+                self.params, jnp.asarray(toks), self.caches,
+                self.kv.tables(),
+                jnp.asarray(np.array([slot], np.int32)),
+                jnp.asarray(np.array([L], np.int32)),
+                jnp.asarray(np.array([st.cached], np.int32)),
+                self._last_logits)
+            self.stats["prefill_dispatches"] += 1
+            self.stats["tokens_prefilled"] += c
+            st.progress += c
+            # pages fully written by this quantum become matchable now —
+            # their writing dispatch is in the device stream
+            self.kv.register_progress(slot, st.tokens, st.progress)
+            if self.sched.advance(rid, c):
+                req = st.req
+                del self._mid[slot]
+                self.active[slot] = req
+                self.kv_len[slot] = L
+                budget = req.max_new_tokens - len(req.generated)
+                self.remaining[slot] = min(budget,
+                                           max(1, self.max_len - 1 - L))
+            else:
+                self.kv_len[slot] = st.progress + 1
+            self._sync_live_peak()
 
     def _decode_tick(self) -> None:
         """One inherited fused decode dispatch, plus token timestamping,
         stream delivery, and completion notification."""
-        before = {rid: len(r.generated) for rid, r in self._reqs.items()
-                  if not r.done}
+        with self._span("decode.harvest"):
+            before = {rid: len(r.generated)
+                      for rid, r in self._reqs.items() if not r.done}
         self._decode_chunk()
-        now = self.clock.now()
-        for rid, n0 in before.items():
-            req = self._reqs[rid]
-            d = len(req.generated) - n0
-            if d > 0:
-                req.token_times.extend([now] * d)
-                self._streams[rid]._push(req.generated[n0:])
-            if req.done:
-                self._close_stream(rid)
+        with self._span("decode.harvest"):
+            now = self.clock.now()
+            for rid, n0 in before.items():
+                req = self._reqs[rid]
+                d = len(req.generated) - n0
+                if d > 0:
+                    req.token_times.extend([now] * d)
+                    self._streams[rid]._push(req.generated[n0:])
+                if req.done:
+                    self._close_stream(rid)
 
     def _close_stream(self, rid: int) -> None:
         self._streams[rid]._close()

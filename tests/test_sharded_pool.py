@@ -91,8 +91,10 @@ def test_sharded_gqa_matches_unsharded_with_prefix_and_cow():
         for e in (e0, e1):
             assert e.stats["prefix_hits"] >= 3, e.stats
             assert e.stats["tokens_reused"] >= 3 * 16, e.stats
-        assert e0.stats == {k: e1.stats[k] for k in e0.stats}, \\
-            (e0.stats, e1.stats)
+        # every count agrees; the host_ns_* phase timings are wall time
+        counts = [k for k in e0.stats if not k.startswith("host_ns_")]
+        assert {k: e0.stats[k] for k in counts} == \\
+            {k: e1.stats[k] for k in counts}, (e0.stats, e1.stats)
 
         m0, m1 = e0.memory_stats(), e1.memory_stats()
         assert m0["sharding"] is None

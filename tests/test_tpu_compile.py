@@ -160,3 +160,33 @@ def test_stablelm_decode_loop_fits_one_chip(one_chip):
     live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             - mem.alias_size_in_bytes + mem.output_size_in_bytes)
     assert live < 16e9, mem
+
+
+@pytest.mark.parametrize("kernel", ["fusemax_decode_paged",
+                                    "fusemax_mla_decode_paged",
+                                    "fusemax_attention"])
+def test_kernel_name_names_the_compiled_instruction(one_chip, kernel):
+    """``pallas_call(name=...)`` becomes the name of the custom call's
+    instruction, the name a device trace's op events carry."""
+    s = lambda shape, dt: _shape(one_chip, shape, dt)
+    bf = jnp.bfloat16
+    if kernel == "fusemax_decode_paged":
+        args = [s((B, H, 1, E), bf), s((P, H, PS, E), bf),
+                s((P, H, PS, E), bf), s((B, W), jnp.int32),
+                s((B,), jnp.int32)]
+        fn = lambda q, k, v, bt, n: ops.fusemax_decode_paged(
+            q, k, v, bt, n, impl="pallas", interpret=False)
+    elif kernel == "fusemax_mla_decode_paged":
+        args = [s((B, HM, 1, R + RD), bf), s((P, PS, R), bf),
+                s((P, PS, RD), bf), s((B, W), jnp.int32),
+                s((B,), jnp.int32)]
+        fn = lambda q, c, kr, bt, n: ops.fusemax_mla_decode_paged(
+            q, c, kr, bt, n, impl="pallas", interpret=False)
+    else:
+        args = [s((1, H, T, E), bf)] * 3
+        fn = lambda q, k, v: ops.fusemax_attention(
+            q, k, v, causal=True, impl="pallas", interpret=False)
+    text = _compile(fn, *args).as_text()
+    named = [ln for ln in text.splitlines()
+             if ln.lstrip().removeprefix("ROOT ").startswith(f"%{kernel}.")]
+    assert named and all("tpu_custom_call" in ln for ln in named)
