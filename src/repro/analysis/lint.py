@@ -190,6 +190,19 @@ def assert_single_sweep(
             f"fiber {fixed}, expected {expected_tiles}")
 
 
+def assert_one_layer(
+    rec: PallasRecord, spec_idx: int, fixed: dict, layer: int,
+    what: str = "K",
+) -> None:
+    """A kernel over pools stacked on a leading layer axis must fetch
+    every tile of the stream from the layer it was handed."""
+    got = {t[0] for t in tile_visits(rec, spec_idx, fixed)}
+    if got != {layer}:
+        raise LintError(
+            f"{rec.name}: {what} tiles come from layers {sorted(got)}, "
+            f"expected only layer {layer}")
+
+
 def assert_stationary(
     rec: PallasRecord, spec_idx: int, sweep_axis: int, fixed: dict,
     what: str = "Q",
@@ -480,6 +493,10 @@ def _probe_decode_pallas(entry: CascadeEntry, p: int = 1) -> dict:
             "grid": rec.grid, "scratch": [s[0] for s in rec.scratch_sig()]}
 
 
+# paged kernels read one layer of pools stacked over a run's layers
+_LAYERS, _LAYER = 3, 1
+
+
 def _probe_decode_paged_pallas(
     entry: CascadeEntry, p: int = 1, quantized: bool = False
 ) -> dict:
@@ -502,12 +519,15 @@ def _probe_decode_paged_pallas(
             kwargs = dict(
                 k_scale=jnp.ones((n_pages, hkv, page_size), jnp.float32),
                 v_scale=jnp.ones((n_pages, hkv, page_size), jnp.float32))
+        kwargs = {k: jnp.stack([v] * _LAYERS) for k, v in kwargs.items()}
         with capture_pallas_calls() as recs:
             fusemax_decode_paged_pallas(
                 jnp.zeros((2 * hkv, g, e), jnp.float32),
-                jnp.zeros((n_pages, hkv, page_size, e), jnp.float32),
-                jnp.zeros((n_pages, hkv, page_size, f), jnp.float32),
-                jnp.asarray(table), kv_len, scale=0.25, hkv=hkv,
+                jnp.zeros((_LAYERS, n_pages, hkv, page_size, e),
+                          jnp.float32),
+                jnp.zeros((_LAYERS, n_pages, hkv, page_size, f),
+                          jnp.float32),
+                jnp.asarray(table), kv_len, _LAYER, scale=0.25, hkv=hkv,
                 splits=2, block_k=block_k, p=p, **kwargs)
         (rec,) = recs
         split_len = (w // 2) * page_size
@@ -525,6 +545,7 @@ def _probe_decode_paged_pallas(
                 streams += [(3, "k_scale"), (4, "v_scale")]
             for si, what in streams:
                 assert_single_sweep(rec, si, {0: b}, n_tiles, live, what)
+                assert_one_layer(rec, si, {0: b}, _LAYER, what)
             assert_stationary(rec, 0, sweep_axis=2, fixed={0: b, 1: 0})
         assert_scratch(rec, [(hkv, g, _LANES), (hkv, g, _LANES),
                              (hkv, g, f)], "RM/RD/RNV per kv head")
@@ -550,9 +571,9 @@ def _probe_mla_decode_paged_pallas(entry: CascadeEntry, p: int = 1) -> dict:
         with capture_pallas_calls() as recs:
             fusemax_mla_decode_paged_pallas(
                 jnp.zeros((2, g, rank + rope), jnp.float32),
-                jnp.zeros((n_pages, page_size, rank), jnp.float32),
-                jnp.zeros((n_pages, page_size, rope), jnp.float32),
-                jnp.asarray(table), kv_len, scale=0.25,
+                jnp.zeros((_LAYERS, n_pages, page_size, rank), jnp.float32),
+                jnp.zeros((_LAYERS, n_pages, page_size, rope), jnp.float32),
+                jnp.asarray(table), kv_len, _LAYER, scale=0.25,
                 splits=2, block_k=block_k, p=p)
         (rec,) = recs
         split_len = (w // 2) * page_size
@@ -566,6 +587,8 @@ def _probe_mla_decode_paged_pallas(entry: CascadeEntry, p: int = 1) -> dict:
             n_tiles = -(-limit // block_k)
             assert_single_sweep(rec, 1, {0: b}, n_tiles, live, "CKV")
             assert_single_sweep(rec, 2, {0: b}, n_tiles, live, "KROPE")
+            assert_one_layer(rec, 1, {0: b}, _LAYER, "CKV")
+            assert_one_layer(rec, 2, {0: b}, _LAYER, "KROPE")
             assert_stationary(rec, 0, sweep_axis=2, fixed={0: b, 1: 0})
         # the [G, r] latent accumulator — the declared MLA running state
         assert_scratch(rec, [(g, _LANES), (g, _LANES), (g, rank)],

@@ -13,11 +13,14 @@ Ragged KV lengths (each sequence in the batch has its own valid prefix of
 the cache) arrive via scalar prefetch (SMEM) and mask the tail chunks.
 
 The *paged* variant (``fusemax_decode_paged_pallas``) reads K/V from a
-head-major page pool ``[num_pages, Hkv, page_size, E]`` through a
-per-sequence block table instead of a dense cache: the block table rides
-in as a second scalar-prefetch operand and the K/V ``index_map``s resolve
-each tile's page id from it, so the sweep touches only the pages the
-sequence owns.  One grid step covers every kv head of a page slice.
+head-major page pool ``[L, num_pages, Hkv, page_size, E]`` stacked over a
+run's layers, through a per-sequence block table instead of a dense
+cache: the block table and the layer index ride in as the second and
+third scalar-prefetch operands and the K/V ``index_map``s resolve each
+tile's (layer, page) from them, so the sweep touches only the pages the
+sequence owns, in the stacked pool the layer loop carries (no layer's
+pool is ever sliced out).  One grid step covers every kv head of a page
+slice.
 Split boundaries stay page-aligned (``splits`` divides the table width,
 ``block_k`` divides ``page_size``) and the partials combine with the same
 associative running-max algebra — the cascade is indifferent to where the
@@ -254,6 +257,12 @@ def _scale_operand(scale: jnp.ndarray) -> jnp.ndarray:
     return scale
 
 
+def _layer_operand(layer) -> jnp.ndarray:
+    """The layer index of a stacked pool as the [1] int32 scalar-prefetch
+    operand the paged kernels' ``index_map``s read."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def _scale_f32(tile: jnp.ndarray) -> jnp.ndarray:
     """A scale tile as fp32, decoding the fp16 bits of an int16 tile (see
     :func:`_scale_operand`).  Stored scales are finite, so the decode
@@ -274,6 +283,7 @@ def _scale_f32(tile: jnp.ndarray) -> jnp.ndarray:
 def _paged_decode_partials_kernel(
     kv_len_ref,                     # SMEM scalar-prefetch: [B] int32
     bt_ref,                         # SMEM scalar-prefetch: [B, W] int32
+    layer_ref,                      # SMEM scalar-prefetch: [1] int32
     q_ref, k_ref, v_ref,
     *refs,                          # [ks_ref, vs_ref,] outputs, scratch
     scale: float,
@@ -371,10 +381,11 @@ def _paged_decode_partials_kernel(
 
 def fusemax_decode_paged_pallas(
     q: jnp.ndarray,            # [BHkv, G, E]  (G padded ≥ 8)
-    k_pages: jnp.ndarray,      # [P, Hkv, page_size, E]
-    v_pages: jnp.ndarray,      # [P, Hkv, page_size, F]
+    k_pages: jnp.ndarray,      # [L, P, Hkv, page_size, E]
+    v_pages: jnp.ndarray,      # [L, P, Hkv, page_size, F]
     block_table: jnp.ndarray,  # [B, W] int32 page ids
     kv_len: jnp.ndarray,       # [B] int32 valid logical lengths
+    layer,                     # int32 scalar: the layer of L to read
     *,
     scale: float,
     softcap: Optional[float] = None,
@@ -384,20 +395,23 @@ def fusemax_decode_paged_pallas(
     exp_impl: str = "native",
     interpret: bool = False,
     p: int = 1,
-    k_scale: Optional[jnp.ndarray] = None,   # [P, Hkv, page_size]
+    k_scale: Optional[jnp.ndarray] = None,   # [L, P, Hkv, page_size]
     v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Paged split-K FuseMax decode. Returns [BHkv, G, F] (q.dtype).
     With ``p > 1`` the G axis folds a verify chain (see the dense kernel).
 
-    The pools are head-major (``[P, Hkv, page_size, E]``), and one grid
-    step loads a ``[Hkv, block_k, E]`` tile — every kv head of one page
-    slice — so each block's last two dims are (``block_k``, full ``E``),
-    which the TPU tiling accepts.  The grid sweeps logical token chunks
-    per sequence; each tile's physical page is looked up in the block
-    table inside the ``index_map`` (standard paged attention: the gather
-    happens in the pipeline's block fetch, never as a materialized
-    [B, T, E] copy).
+    The pools are head-major and stacked over layers
+    (``[L, P, Hkv, page_size, E]``), and one grid step loads a
+    ``[Hkv, block_k, E]`` tile — every kv head of one page slice of layer
+    ``layer`` — so each block's last two dims are (``block_k``, full
+    ``E``), which the TPU tiling accepts.  The grid sweeps logical token
+    chunks per sequence; each tile's physical page is looked up in the
+    block table inside the ``index_map`` (standard paged attention: the
+    gather happens in the pipeline's block fetch, never as a materialized
+    [B, T, E] copy), and ``layer`` (a scalar-prefetch operand, traced
+    inside a layer loop) picks the layer, so the DMA reads the stacked pool
+    in place.
 
     ``k_scale``/``v_scale`` (quantized pools) stream per-token scale
     tiles ``[Hkv, block_k]`` through the same block-table ``index_map``.
@@ -406,7 +420,7 @@ def fusemax_decode_paged_pallas(
     ``block_k % 128 == 0``.
     """
     bh, g, e = q.shape
-    n_pages, hkv_p, page_size, f = v_pages.shape
+    _, n_pages, hkv_p, page_size, f = v_pages.shape
     b, w = block_table.shape
     if g % p:
         raise ValueError(f"folded q rows {g} not divisible by p={p}")
@@ -450,27 +464,29 @@ def fusemax_decode_paged_pallas(
         # kv_len in the kernel body, so the content never contributes
         return jnp.minimum(bt_ref[b_i, page_slot], n_pages - 1)
 
-    def _kv_index(b_i, s, m2_i, kv_len_ref, bt_ref):
-        return (_page(b_i, s, m2_i, bt_ref), 0, m2_i % blocks_per_page, 0)
+    def _kv_index(b_i, s, m2_i, kv_len_ref, bt_ref, layer_ref):
+        return (layer_ref[0], _page(b_i, s, m2_i, bt_ref), 0,
+                m2_i % blocks_per_page, 0)
 
-    def _scale_index(b_i, s, m2_i, kv_len_ref, bt_ref):
-        return (_page(b_i, s, m2_i, bt_ref), 0, m2_i % blocks_per_page)
+    def _scale_index(b_i, s, m2_i, kv_len_ref, bt_ref, layer_ref):
+        return (layer_ref[0], _page(b_i, s, m2_i, bt_ref), 0,
+                m2_i % blocks_per_page)
 
     in_specs = [
         pl.BlockSpec((hkv, g, e), lambda b_i, s, m2_i, *_: (b_i, 0, 0)),
-        pl.BlockSpec((1, hkv, block_k, e), _kv_index),
-        pl.BlockSpec((1, hkv, block_k, f), _kv_index),
+        pl.BlockSpec((None, 1, hkv, block_k, e), _kv_index),
+        pl.BlockSpec((None, 1, hkv, block_k, f), _kv_index),
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, hkv, block_k), _scale_index),
-            pl.BlockSpec((1, hkv, block_k), _scale_index),
+            pl.BlockSpec((None, 1, hkv, block_k), _scale_index),
+            pl.BlockSpec((None, 1, hkv, block_k), _scale_index),
         ]
         operands += [_scale_operand(k_scale), _scale_operand(v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -502,7 +518,7 @@ def fusemax_decode_paged_pallas(
         name="fusemax_decode_paged",
         interpret=interpret,
     )(kv_len.astype(jnp.int32), block_table.astype(jnp.int32),
-      *operands)
+      _layer_operand(layer), *operands)
 
     return _combine_partials(pm, pl_, pnv, q.dtype)
 
@@ -648,6 +664,7 @@ def mla_verify_chain_cascade() -> Cascade:
 def _mla_paged_decode_partials_kernel(
     kv_len_ref,                     # SMEM scalar-prefetch: [B] int32
     bt_ref,                         # SMEM scalar-prefetch: [B, W] int32
+    layer_ref,                      # SMEM scalar-prefetch: [1] int32
     q_ref, ckv_ref, krope_ref,
     *refs,                          # [cs_ref, krs_ref,] outputs, scratch
     scale: float,
@@ -749,10 +766,11 @@ def _mla_paged_decode_partials_kernel(
 
 def fusemax_mla_decode_paged_pallas(
     q: jnp.ndarray,             # [B, G, rank + rope_dim]  (G padded ≥ 8)
-    ckv_pages: jnp.ndarray,     # [P, page_size, rank]
-    krope_pages: jnp.ndarray,   # [P, page_size, rope_dim]
+    ckv_pages: jnp.ndarray,     # [L, P, page_size, rank]
+    krope_pages: jnp.ndarray,   # [L, P, page_size, rope_dim]
     block_table: jnp.ndarray,   # [B, W] int32 page ids
     kv_len: jnp.ndarray,        # [B] int32 valid logical lengths
+    layer,                      # int32 scalar: the layer of L to read
     *,
     scale: float,
     softcap: Optional[float] = None,
@@ -761,7 +779,7 @@ def fusemax_mla_decode_paged_pallas(
     exp_impl: str = "native",
     interpret: bool = False,
     p: int = 1,
-    ckv_scale: Optional[jnp.ndarray] = None,   # [P, page_size]
+    ckv_scale: Optional[jnp.ndarray] = None,   # [L, P, page_size]
     krope_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Paged split-K MLA decode in latent space. Returns [B, G, rank]
@@ -769,15 +787,16 @@ def fusemax_mla_decode_paged_pallas(
 
     Same structure as :func:`fusemax_decode_paged_pallas` with Hkv = 1 and
     two page streams whose ``index_map``s resolve the same block-table
-    slot: the latent pages double as the V stream (F = rank), so per-step
-    decode DMAs exactly the slot's pages and nothing else.
+    slot of layer ``layer`` of the stacked pools: the latent pages double
+    as the V stream (F = rank), so per-step decode DMAs exactly the slot's
+    pages and nothing else.
 
-    Quantized scale pools enter the kernel as ``[P, 1, page_size]`` views
-    tiled ``(1, 1, block_k)``, so ``block_k`` must be the whole page or a
-    multiple of 128 lanes.
+    Quantized scale pools enter the kernel as ``[L, P, 1, page_size]``
+    views tiled ``(1, 1, block_k)``, so ``block_k`` must be the whole page
+    or a multiple of 128 lanes.
     """
     b, g, e = q.shape
-    n_pages, page_size, rank = ckv_pages.shape
+    n_layers, n_pages, page_size, rank = ckv_pages.shape
     rope_dim = krope_pages.shape[-1]
     bt_b, w = block_table.shape
     if g % p:
@@ -823,29 +842,31 @@ def fusemax_mla_decode_paged_pallas(
         # kv_len mask in the body keeps their content out of the cascade
         return jnp.minimum(bt_ref[b_i, page_slot], n_pages - 1)
 
-    def _page_index(b_i, s, m2_i, kv_len_ref, bt_ref):
-        return (_page(b_i, s, m2_i, bt_ref), m2_i % blocks_per_page, 0)
+    def _page_index(b_i, s, m2_i, kv_len_ref, bt_ref, layer_ref):
+        return (layer_ref[0], _page(b_i, s, m2_i, bt_ref),
+                m2_i % blocks_per_page, 0)
 
-    def _scale_index(b_i, s, m2_i, kv_len_ref, bt_ref):
-        return (_page(b_i, s, m2_i, bt_ref), 0, m2_i % blocks_per_page)
+    def _scale_index(b_i, s, m2_i, kv_len_ref, bt_ref, layer_ref):
+        return (layer_ref[0], _page(b_i, s, m2_i, bt_ref), 0,
+                m2_i % blocks_per_page)
 
     in_specs = [
         pl.BlockSpec((1, g, e), lambda b_i, s, m2_i, *_: (b_i, 0, 0)),
-        pl.BlockSpec((1, block_k, rank), _page_index),
-        pl.BlockSpec((1, block_k, rope_dim), _page_index),
+        pl.BlockSpec((None, 1, block_k, rank), _page_index),
+        pl.BlockSpec((None, 1, block_k, rope_dim), _page_index),
     ]
     operands = [q, ckv_pages, krope_pages]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, 1, block_k), _scale_index),
-            pl.BlockSpec((1, 1, block_k), _scale_index),
+            pl.BlockSpec((None, 1, 1, block_k), _scale_index),
+            pl.BlockSpec((None, 1, 1, block_k), _scale_index),
         ]
-        operands += [
-            _scale_operand(ckv_scale).reshape(n_pages, 1, page_size),
-            _scale_operand(krope_scale).reshape(n_pages, 1, page_size)]
+        view = (n_layers, n_pages, 1, page_size)
+        operands += [_scale_operand(ckv_scale).reshape(view),
+                     _scale_operand(krope_scale).reshape(view)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -877,6 +898,6 @@ def fusemax_mla_decode_paged_pallas(
         name="fusemax_mla_decode_paged",
         interpret=interpret,
     )(kv_len.astype(jnp.int32), block_table.astype(jnp.int32),
-      *operands)
+      _layer_operand(layer), *operands)
 
     return _combine_partials(pm, pl_, pnv, q.dtype)

@@ -502,13 +502,20 @@ def _scale_block_k(block_k: int, page_size: int, quantized: bool) -> int:
     return block_k
 
 
+def _lift_layer(*pools):
+    """A one-layer pool is the ``L = 1`` case of a pool stacked over
+    layers: ``pool[None]``, read at layer 0 (``None`` passes through)."""
+    return [None if a is None else a[None] for a in pools]
+
+
 def fusemax_decode_paged(
     q: jnp.ndarray,            # [B, Hq, 1, E]
-    k_pages: jnp.ndarray,      # [P, Hkv, page_size, E]
-    v_pages: jnp.ndarray,      # [P, Hkv, page_size, F]
+    k_pages: jnp.ndarray,      # [L, P, Hkv, page_size, E] or [P, ...]
+    v_pages: jnp.ndarray,      # [L, P, Hkv, page_size, F] or [P, ...]
     block_table: jnp.ndarray,  # [B, W] int32 page ids
     kv_len: jnp.ndarray,       # [B] valid logical lengths
     *,
+    layer=0,
     capacity: Optional[int] = None,
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
@@ -517,10 +524,17 @@ def fusemax_decode_paged(
     block_k: Optional[int] = None,
     exp_impl: str = "native",
     interpret: Optional[bool] = None,
-    k_scale: Optional[jnp.ndarray] = None,   # [P, Hkv, page_size]
+    k_scale: Optional[jnp.ndarray] = None,   # [L, P, Hkv, page_size]
     v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Single-token decode against a *paged* KV cache.
+
+    The pools are stacked over a run's layers and ``layer`` (an int or a
+    traced int32 scalar, e.g. a layer loop's counter) selects the one to
+    read; a four-dimensional pool is a single layer (``L = 1``, layer 0).
+    The Pallas kernel reads the stacked pool in place through its
+    ``index_map``; the jnp/ref paths gather from ``pool[layer]``, the same
+    arithmetic.
 
     ``capacity`` truncates the logical view to that many tokens (ring
     caches: capacity = window, which may not fill the last page) — with it,
@@ -545,8 +559,11 @@ def fusemax_decode_paged(
     replicated; page ids are global) and get results bit-identical to
     the corresponding head slice of the full-pool call.
     """
+    if v_pages.ndim == 4:
+        k_pages, v_pages, k_scale, v_scale = _lift_layer(
+            k_pages, v_pages, k_scale, v_scale)
     b, hq, p, e = q.shape
-    n_pages, hkv, page_size, f = v_pages.shape
+    hkv, page_size, f = v_pages.shape[2:]
     w = block_table.shape[1]
     group = hq // hkv
     scale = scale if scale is not None else 1.0 / (e ** 0.5)
@@ -559,11 +576,11 @@ def fusemax_decode_paged(
         # autotuned splits, same arithmetic as the dense layout (P > 1 —
         # the speculative verify chain — rides the same delegation)
         cap = w * page_size if capacity is None else capacity
-        k = gather_kv_pages(k_pages, block_table)
-        v = gather_kv_pages(v_pages, block_table)
+        k = gather_kv_pages(k_pages[layer], block_table)
+        v = gather_kv_pages(v_pages[layer], block_table)
         if k_scale is not None:
-            ks = gather_kv_pages(k_scale, block_table)
-            vs = gather_kv_pages(v_scale, block_table)
+            ks = gather_kv_pages(k_scale[layer], block_table)
+            vs = gather_kv_pages(v_scale[layer], block_table)
             k = k.astype(jnp.float32) * ks.astype(jnp.float32)[..., None]
             v = v.astype(jnp.float32) * vs.astype(jnp.float32)[..., None]
         return fusemax_decode(
@@ -594,7 +611,7 @@ def fusemax_decode_paged(
     interpret = (not _on_tpu()) if interpret is None else interpret
     out = fusemax_decode_paged_pallas(
         _fold_decode_q(q, b, hkv, group, e), k_pages, v_pages,
-        block_table, kv_len,
+        block_table, kv_len, layer,
         scale=scale, softcap=softcap, hkv=hkv, splits=splits,
         block_k=block_k, exp_impl=exp_impl, interpret=interpret, p=p,
         k_scale=k_scale, v_scale=v_scale,
@@ -716,11 +733,12 @@ def mla_verify_combine(pm, pl_, pnv, dtype) -> jnp.ndarray:
 
 def fusemax_mla_decode_paged(
     q: jnp.ndarray,             # [B, H, 1, rank + rope_dim] absorbed q_cat
-    ckv_pages: jnp.ndarray,     # [P, page_size, rank]
-    krope_pages: jnp.ndarray,   # [P, page_size, rope_dim]
+    ckv_pages: jnp.ndarray,     # [L, P, page_size, rank] or [P, ...]
+    krope_pages: jnp.ndarray,   # [L, P, page_size, rope_dim] or [P, ...]
     block_table: jnp.ndarray,   # [B, W] int32 page ids
     kv_len: jnp.ndarray,        # [B] valid logical lengths
     *,
+    layer=0,
     scale: Optional[float] = None,
     softcap: Optional[float] = None,
     impl: str = "auto",
@@ -728,10 +746,12 @@ def fusemax_mla_decode_paged(
     block_k: Optional[int] = None,
     exp_impl: str = "native",
     interpret: Optional[bool] = None,
-    ckv_scale: Optional[jnp.ndarray] = None,   # [P, page_size] fp32
+    ckv_scale: Optional[jnp.ndarray] = None,   # [L, P, page_size]
     krope_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """Single-token MLA decode against a paged *latent* cache.
+    """Single-token MLA decode against a paged *latent* cache, read at
+    ``layer`` of pools stacked over layers (a three-dimensional pool is a
+    single layer) — the contract of :func:`fusemax_decode_paged`.
 
     Queries arrive W_uk-absorbed (``q_eff = q_nopeᵀW_uk`` concatenated
     with ``q_rope``); the result is the latent output [B, H, 1, rank],
@@ -747,8 +767,11 @@ def fusemax_mla_decode_paged(
     (``splits``/``block_k`` are ignored on this path).  impl="ref"
     delegates to the 3-pass oracle over the gathered view.
     """
+    if ckv_pages.ndim == 3:
+        ckv_pages, krope_pages, ckv_scale, krope_scale = _lift_layer(
+            ckv_pages, krope_pages, ckv_scale, krope_scale)
     b, hq, p, e = q.shape
-    n_pages, page_size, rank = ckv_pages.shape
+    page_size, rank = ckv_pages.shape[2:]
     rope_dim = krope_pages.shape[-1]
     w = block_table.shape[1]
     if e != rank + rope_dim:
@@ -759,11 +782,11 @@ def fusemax_mla_decode_paged(
         impl = "pallas" if _on_tpu() else "jnp"
 
     if impl in ("jnp", "ref"):
-        ckv = gather_pages(ckv_pages, block_table)          # [B, W·ps, r]
-        kr = gather_pages(krope_pages, block_table)
+        ckv = gather_pages(ckv_pages[layer], block_table)   # [B, W·ps, r]
+        kr = gather_pages(krope_pages[layer], block_table)
         if ckv_scale is not None:
-            cs = gather_pages(ckv_scale, block_table)       # [B, W·ps]
-            ks = gather_pages(krope_scale, block_table)
+            cs = gather_pages(ckv_scale[layer], block_table)    # [B, W·ps]
+            ks = gather_pages(krope_scale[layer], block_table)
             ckv = ckv.astype(jnp.float32) * cs.astype(jnp.float32)[..., None]
             kr = kr.astype(jnp.float32) * ks.astype(jnp.float32)[..., None]
         if impl == "ref":
@@ -804,7 +827,7 @@ def fusemax_mla_decode_paged(
     interpret = (not _on_tpu()) if interpret is None else interpret
     out = fusemax_mla_decode_paged_pallas(
         _fold_decode_q(q, b, 1, hq, e), ckv_pages, krope_pages,
-        block_table, kv_len,
+        block_table, kv_len, layer,
         scale=scale, softcap=softcap, splits=splits, block_k=block_k,
         exp_impl=exp_impl, interpret=interpret, p=p,
         ckv_scale=ckv_scale, krope_scale=krope_scale,

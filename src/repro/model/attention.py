@@ -25,7 +25,10 @@ memory tracks live tokens, see :mod:`repro.serving.kv_cache`):
   windowed layer cycles through a fixed ceil(window/page_size)-page
   working set no matter how long the sequence runs) and ``max_len`` for
   global/MLA layers.  Logical index == gathered index, so paged reads are
-  bit-identical to the dense layout's.
+  bit-identical to the dense layout's.  Prefill scatters into one layer's
+  pool; decode and verify take the pools of a whole run, stacked
+  ``[L, ...]``, plus the layer index, and write that layer's new rows in
+  place (:func:`write_page_rows`) before the kernel reads them there.
 
 Length-bucketed prefill: the ``true_len`` argument on the prefill entry
 points marks each row's real prompt length inside a padded (power-of-two
@@ -110,6 +113,54 @@ def write_kv_pages(pages: jnp.ndarray, bt_rows: jnp.ndarray,
     heads = jnp.arange(pages.shape[1])[None, :, None]
     return pages.at[page[:, None], heads, slot[:, None]].set(
         values.astype(pages.dtype), mode="drop")
+
+
+def write_page_rows(pool: jnp.ndarray, layer, bt_rows: jnp.ndarray,
+                    positions: jnp.ndarray, values: jnp.ndarray,
+                    capacity: int, valid: jnp.ndarray,
+                    tok: int) -> jnp.ndarray:
+    """Write per-token rows into layer ``layer`` of a page pool stacked
+    over layers, in place: the decode-time counterpart of
+    :func:`write_pages` / :func:`write_kv_pages` for the pools a layer
+    loop carries.
+
+    pool: [L, P, *page], where axis ``tok`` of ``page`` is the in-page
+    token slot (GQA pages [Hkv, ps, dh] and scales [Hkv, ps]: 1; MLA
+    pages [ps, r] and scales [ps]: 0); values: [N, *page] with the S new
+    tokens on that axis; positions, valid: [N, S].  Same wrap rule as
+    :func:`write_pages`.
+
+    Each token is one ``dynamic_update_slice`` of a one-token row, which
+    XLA performs in place on the carried buffer and in the layout the
+    paged kernel reads (a scatter would get a layout of its own, and a
+    whole-pool copy into the kernel's layout per layer).  Such a start is
+    clamped, not dropped, so a row that is not ``valid``, or whose table
+    entry is out of range (the sentinel id ``P``), writes back the row it
+    reads and never lands on a real page."""
+    n_pages = pool.shape[1]
+    page, slot = _page_slots(n_pages, pool.shape[2 + tok], bt_rows,
+                             positions, capacity, None)
+    ok = valid & (page >= 0) & (page < n_pages)
+    lead = (0,) * tok
+    tail = (0,) * (pool.ndim - 3 - tok)
+    for n in range(values.shape[0]):
+        for t in range(values.shape[1 + tok]):
+            row = jax.lax.slice_in_dim(values[n], t, t + 1, axis=tok)
+            row = row[None, None].astype(pool.dtype)
+            start = (layer, page[n, t], *lead, slot[n, t], *tail)
+            old = jax.lax.dynamic_slice(pool, start, row.shape)
+            pool = jax.lax.dynamic_update_slice(
+                pool, jnp.where(ok[n, t], row, old), start)
+    return pool
+
+
+def _write_rows(pools: dict, new: dict, layer, bt_rows, positions,
+                capacity: int, valid, tok: int) -> dict:
+    """:func:`write_page_rows` of each pool named in ``new`` (name → the
+    rows to write) → the updated pools."""
+    return {k: write_page_rows(pools[k], layer, bt_rows, positions, v,
+                               capacity, valid, tok)
+            for k, v in new.items()}
 
 
 def _page_slots(n_pages: int, page_size: int, bt_rows, positions,
@@ -415,7 +466,7 @@ def _gqa_capacity(cache: dict, bt_rows: jnp.ndarray,
                   spec: LayerSpec) -> int:
     """Logical token capacity of a paged GQA cache: the window for local
     layers (ring eviction), the full table span for global layers."""
-    page_size = cache["k_pages"].shape[2]
+    page_size = cache["k_pages"].shape[-2]
     return spec.window if spec.window is not None \
         else bt_rows.shape[1] * page_size
 
@@ -642,14 +693,52 @@ def gqa_prefill_paged(
     return y, new_cache
 
 
+def _gqa_write_attend(pools: dict, new: dict, layer, q, bt_rows, pos,
+                      valid, kv_len, cap: int, capacity: Optional[int],
+                      cfg: ModelConfig, rt: Runtime):
+    """Write a step's K/V (and scale) rows into layer ``layer`` of the
+    carried pools (:func:`write_page_rows`), then run the paged decode
+    kernel on them → (attention out [B, H, P, dh], updated pools).  Per
+    kv head throughout, so it runs unchanged on a kv-head shard under
+    ``shard_map``."""
+    pools = _write_rows(pools, new, layer, bt_rows, pos, cap, valid, tok=1)
+    out = fusemax_decode_paged(
+        q, pools["k_pages"], pools["v_pages"], bt_rows, kv_len,
+        layer=layer,
+        capacity=capacity,
+        softcap=cfg.attn_softcap,
+        impl=rt.attn_impl,
+        splits=rt.decode_splits,
+        exp_impl=rt.exp_impl,
+        interpret=rt.interpret,
+        k_scale=pools.get("k_scale"),
+        v_scale=pools.get("v_scale"),
+    )
+    return out, pools
+
+
+def _gqa_new_rows(cache: dict, k_new: jnp.ndarray,
+                  v_new: jnp.ndarray) -> dict:
+    """Pool name → the rows a step writes (quantized with their scales
+    where the pool is; see :func:`_gqa_quant_new`)."""
+    k_q, k_s, v_q, v_s, _, _ = _gqa_quant_new(cache, k_new, v_new)
+    new = {"k_pages": k_q, "v_pages": v_q}
+    if k_s is not None:
+        new.update(k_scale=k_s, v_scale=v_s)
+    return new
+
+
 def gqa_decode_paged(
     p, x: jnp.ndarray, cache: dict, bt_rows: jnp.ndarray,
     kv_len: jnp.ndarray, cfg: ModelConfig, spec: LayerSpec, rt: Runtime,
+    layer,
 ) -> tuple[jnp.ndarray, dict]:
-    """One-token decode against the page pool: write the new K/V at the
-    logical tail (ring-wrapped for local layers), read through the block
-    table.  Inactive slots (kv_len == 0) drop their writes — their table
-    rows may hold the sentinel page.
+    """One-token decode as layer ``layer`` of pools stacked over a run's
+    layers (``cache`` leaves [L, P, Hkv, ps, dh], scales [L, P, Hkv, ps]):
+    write the new K/V rows in place at the logical tail (ring-wrapped for
+    local layers), read through the block table.  Inactive slots
+    (kv_len == 0) write nothing — their table rows may hold the sentinel
+    page.
 
     With ``rt.kv_shard`` the write + split-K decode run head-parallel
     under ``shard_map`` (each device decodes its kv-head slice of the
@@ -667,101 +756,37 @@ def gqa_decode_paged(
         eff_len = kv_len
         capacity = None
 
-    k_q, k_s, v_q, v_s, _, _ = _gqa_quant_new(cache, k_new, v_new)
-
+    new = _gqa_new_rows(cache, k_new, v_new)
+    pools = {k: cache[k] for k in new}
     shard = rt.kv_shard
-    if shard is not None:
-        pspec = shard.spec(4, 1)
-        hspec = shard.spec(4, 1)
-        rep = shard.replicated
-
-        if k_s is not None:
-            sspec = shard.spec(3, 1)
-            hspec3 = shard.spec(3, 1)
-
-            def local_q(kp, vp, ksp, vsp, q_l, kq_l, vq_l, ks_l, vs_l, bt,
-                        pos_b, val, el):
-                kp = write_kv_pages(kp, bt, pos_b, kq_l,
-                                 cap, val)
-                vp = write_kv_pages(vp, bt, pos_b, vq_l,
-                                 cap, val)
-                ksp = write_kv_pages(ksp, bt, pos_b, ks_l,
-                                  cap, val)
-                vsp = write_kv_pages(vsp, bt, pos_b, vs_l,
-                                  cap, val)
-                out = fusemax_decode_paged(
-                    q_l, kp, vp, bt, el,
-                    capacity=capacity, softcap=cfg.attn_softcap,
-                    impl=rt.attn_impl, splits=rt.decode_splits,
-                    exp_impl=rt.exp_impl, interpret=rt.interpret,
-                    k_scale=ksp, v_scale=vsp,
-                )
-                out = jax.lax.all_gather(out, shard.axis, axis=1,
-                                         tiled=True)
-                return out, kp, vp, ksp, vsp
-
-            out, k_pages, v_pages, k_sc, v_sc = jax.shard_map(
-                local_q, mesh=shard.mesh, check_vma=False,
-                in_specs=(pspec, pspec, sspec, sspec, hspec, hspec, hspec,
-                          hspec3, hspec3, rep, rep, rep, rep),
-                out_specs=(rep, pspec, pspec, sspec, sspec),
-            )(cache["k_pages"], cache["v_pages"], cache["k_scale"],
-              cache["v_scale"], q, k_q, v_q, k_s, v_s, bt_rows, pos,
-              valid, eff_len)
-            y = jnp.einsum("bhse,hed->bsd", out, p["wo"].astype(x.dtype))
-            return y, {"k_pages": k_pages, "v_pages": v_pages,
-                       "k_scale": k_sc, "v_scale": v_sc}
-
-        def local(kp, vp, q_l, kn_l, vn_l, bt, pos_b, val, el):
-            kp = write_kv_pages(kp, bt, pos_b, kn_l, cap,
-                             val)
-            vp = write_kv_pages(vp, bt, pos_b, vn_l, cap,
-                             val)
-            out = fusemax_decode_paged(
-                q_l, kp, vp, bt, el,
-                capacity=capacity, softcap=cfg.attn_softcap,
-                impl=rt.attn_impl, splits=rt.decode_splits,
-                exp_impl=rt.exp_impl, interpret=rt.interpret,
-            )
+    if shard is None:
+        out, pools = _gqa_write_attend(pools, new, layer, q, bt_rows, pos,
+                                       valid, eff_len, cap, capacity, cfg,
+                                       rt)
+    else:
+        def local(pools, new_l, q_l, bt, pos_b, val, el, lyr):
+            out, pools = _gqa_write_attend(pools, new_l, lyr, q_l, bt,
+                                           pos_b, val, el, cap, capacity,
+                                           cfg, rt)
             out = jax.lax.all_gather(out, shard.axis, axis=1, tiled=True)
-            return out, kp, vp
+            return out, pools
 
-        out, k_pages, v_pages = jax.shard_map(
+        # pages and scales shard their Hkv axis (third / second from the
+        # right, as ``sharding.paged_cache_shardings`` places them); the
+        # new rows and q shard their head axis
+        pspecs = {k: shard.spec(a.ndim, -3 if k.endswith("_pages") else -2)
+                  for k, a in pools.items()}
+        nspecs = {k: shard.spec(a.ndim, 1) for k, a in new.items()}
+        rep = shard.replicated
+        out, pools = jax.shard_map(
             local, mesh=shard.mesh, check_vma=False,
-            in_specs=(pspec, pspec, hspec, hspec, hspec, rep, rep, rep,
+            in_specs=(pspecs, nspecs, shard.spec(4, 1), rep, rep, rep, rep,
                       rep),
-            out_specs=(rep, pspec, pspec),
-        )(cache["k_pages"], cache["v_pages"], q, k_new, v_new, bt_rows,
-          pos, valid, eff_len)
-        y = jnp.einsum("bhse,hed->bsd", out, p["wo"].astype(x.dtype))
-        return y, {"k_pages": k_pages, "v_pages": v_pages}
-
-    new_cache = {
-        "k_pages": write_kv_pages(cache["k_pages"], bt_rows, pos,
-                               k_q, cap, valid),
-        "v_pages": write_kv_pages(cache["v_pages"], bt_rows, pos,
-                               v_q, cap, valid),
-    }
-    if k_s is not None:
-        new_cache["k_scale"] = write_kv_pages(
-            cache["k_scale"], bt_rows, pos, k_s, cap,
-            valid)
-        new_cache["v_scale"] = write_kv_pages(
-            cache["v_scale"], bt_rows, pos, v_s, cap,
-            valid)
-    out = fusemax_decode_paged(
-        q, new_cache["k_pages"], new_cache["v_pages"], bt_rows, eff_len,
-        capacity=capacity,
-        softcap=cfg.attn_softcap,
-        impl=rt.attn_impl,
-        splits=rt.decode_splits,
-        exp_impl=rt.exp_impl,
-        interpret=rt.interpret,
-        k_scale=new_cache.get("k_scale"),
-        v_scale=new_cache.get("v_scale"),
-    )                                                    # [B, H, 1, dh]
+            out_specs=(rep, pspecs),
+        )(pools, new, q, bt_rows, pos, valid, eff_len,
+          jnp.asarray(layer, jnp.int32))
     y = jnp.einsum("bhse,hed->bsd", out, p["wo"].astype(x.dtype))
-    return y, new_cache
+    return y, pools
 
 
 def gqa_verify(
@@ -805,10 +830,11 @@ def gqa_verify(
 def gqa_verify_paged(
     p, x: jnp.ndarray, cache: dict, bt_rows: jnp.ndarray,
     kv_len: jnp.ndarray, span: jnp.ndarray,
-    cfg: ModelConfig, spec: LayerSpec, rt: Runtime,
+    cfg: ModelConfig, spec: LayerSpec, rt: Runtime, layer,
 ) -> tuple[jnp.ndarray, dict]:
-    """Paged :func:`gqa_verify`: chain K/V lands through the block table
-    (the tail rows are the slot's scratch draft pages — see
+    """Paged :func:`gqa_verify` as layer ``layer`` of stacked pools (see
+    :func:`gqa_decode_paged`): chain K/V rows land through the block
+    table (the tail rows are the slot's scratch draft pages — see
     ``PagedKVCache.reserve_draft``), the verify kernel reads back through
     the same table.  Unsharded only (the engine gates speculation off
     under a device mesh)."""
@@ -818,32 +844,12 @@ def gqa_verify_paged(
     cap = _gqa_capacity(cache, bt_rows, spec)
     valid = (jnp.arange(pq)[None] < span[:, None]) & (kv_len > 0)[:, None]
 
-    k_q, k_s, v_q, v_s, _, _ = _gqa_quant_new(cache, k_new, v_new)
-    new_cache = {
-        "k_pages": write_kv_pages(cache["k_pages"], bt_rows, pos,
-                               k_q, cap, valid),
-        "v_pages": write_kv_pages(cache["v_pages"], bt_rows, pos,
-                               v_q, cap, valid),
-    }
-    if k_s is not None:
-        new_cache["k_scale"] = write_kv_pages(
-            cache["k_scale"], bt_rows, pos, k_s, cap,
-            valid)
-        new_cache["v_scale"] = write_kv_pages(
-            cache["v_scale"], bt_rows, pos, v_s, cap,
-            valid)
-    out = fusemax_decode_paged(
-        q, new_cache["k_pages"], new_cache["v_pages"], bt_rows, kv_len,
-        softcap=cfg.attn_softcap,
-        impl=rt.attn_impl,
-        splits=rt.decode_splits,
-        exp_impl=rt.exp_impl,
-        interpret=rt.interpret,
-        k_scale=new_cache.get("k_scale"),
-        v_scale=new_cache.get("v_scale"),
-    )                                                    # [B, H, P, dh]
+    new = _gqa_new_rows(cache, k_new, v_new)
+    out, pools = _gqa_write_attend({k: cache[k] for k in new}, new, layer,
+                                   q, bt_rows, pos, valid, kv_len, cap, None,
+                                   cfg, rt)              # [B, H, P, dh]
     y = jnp.einsum("bhse,hed->bsd", out, p["wo"].astype(x.dtype))
-    return y, new_cache
+    return y, pools
 
 
 # ---------------------------------------------------------------------------
@@ -1232,8 +1238,12 @@ def mla_prefill_paged(
 def mla_decode_paged(
     p, x: jnp.ndarray, cache: dict, bt_rows: jnp.ndarray,
     kv_len: jnp.ndarray, cfg: ModelConfig, spec: LayerSpec, rt: Runtime,
+    layer,
 ) -> tuple[jnp.ndarray, dict]:
-    """Absorbed-form decode against paged latents, one split per page.
+    """Absorbed-form decode against paged latents, one split per page, as
+    layer ``layer`` of pools stacked over a run's layers (``cache`` leaves
+    [L, P, ps, r], scales [L, P, ps]; rows written in place as in
+    :func:`gqa_decode_paged`).
 
     Unsharded, the step dispatches to
     :func:`repro.kernels.ops.fusemax_mla_decode_paged`: on TPU the true
@@ -1260,7 +1270,7 @@ def mla_decode_paged(
     dt = x.dtype
     pos = (kv_len - 1)[:, None]
     q_nope, q_rope, ckv_new, krope_new = _mla_qkv_latent(p, x, cfg, pos)
-    page_size = cache["ckv_pages"].shape[1]
+    page_size = cache["ckv_pages"].shape[2]
     w = bt_rows.shape[1]
     cap = w * page_size
     valid = (kv_len > 0)[:, None]
@@ -1272,27 +1282,30 @@ def mla_decode_paged(
     # latent scales span the full (sharded) rank axis, so quantization
     # and the replicated scale-pool writes happen outside shard_map
     ckv_q, ckv_s, kr_q, kr_s = _mla_quant_new(cache, ckv_new, krope_new)
-    scale_new = {} if ckv_s is None else _mla_write_scales(
-        cache, bt_rows, pos, ckv_s, kr_s, cap, valid)
+    scale_new = {} if ckv_s is None else _write_rows(
+        cache, {"ckv_scale": ckv_s, "krope_scale": kr_s}, layer, bt_rows,
+        pos, cap, valid, tok=0)
 
     shard = rt.kv_shard
     if shard is not None:
         sp = w // shard.size                 # pages swept per device
 
-        def local(cp, krp, cn_l, kn_l, qc, bt, pos_b, val, kvl,
+        def local(cp, krp, cn_l, kn_l, qc, bt, pos_b, val, kvl, lyr,
                   csp=None, krsp=None):
-            cp = write_pages(cp, bt, pos_b, cn_l, cap, val)
-            krp = write_pages(krp, bt, pos_b, kn_l, cap, val)
-            ckv = jax.lax.all_gather(gather_pages(cp, bt), shard.axis,
+            got = _write_rows({"ckv_pages": cp, "krope_pages": krp},
+                              {"ckv_pages": cn_l, "krope_pages": kn_l},
+                              lyr, bt, pos_b, cap, val, tok=0)
+            cp, krp = got["ckv_pages"], got["krope_pages"]
+            ckv = jax.lax.all_gather(gather_pages(cp[lyr], bt), shard.axis,
                                      axis=2, tiled=True)
-            kr = jax.lax.all_gather(gather_pages(krp, bt), shard.axis,
+            kr = jax.lax.all_gather(gather_pages(krp[lyr], bt), shard.axis,
                                     axis=2, tiled=True)
             if csp is not None:
-                # scale pools are replicated [P, ps]; the all-gathered
+                # scale pools are replicated [L, P, ps]; the all-gathered
                 # views are rank-complete, so dequant matches unsharded
-                ckv = dequantize_kv(ckv, gather_pages(csp, bt),
+                ckv = dequantize_kv(ckv, gather_pages(csp[lyr], bt),
                                     jnp.float32)
-                kr = dequantize_kv(kr, gather_pages(krsp, bt),
+                kr = dequantize_kv(kr, gather_pages(krsp[lyr], bt),
                                    jnp.float32)
             d = jax.lax.axis_index(shard.axis)
             pm, pl_, pnv = mla_decode_partials(
@@ -1303,11 +1316,13 @@ def mla_decode_paged(
             pnv = jax.lax.all_gather(pnv, shard.axis, axis=1, tiled=True)
             return mla_combine_partials(pm, pl_, pnv, qc.dtype), cp, krp
 
-        pspec = shard.spec(3, -1)
+        pspec = shard.spec(4, -1)
+        nspec = shard.spec(3, -1)
         rep = shard.replicated
-        specs = [pspec, pspec, pspec, pspec, rep, rep, rep, rep, rep]
+        specs = [pspec, pspec, nspec, nspec, rep, rep, rep, rep, rep, rep]
         operands = [cache["ckv_pages"], cache["krope_pages"], ckv_q, kr_q,
-                    q_cat, bt_rows, pos, valid, kv_len]
+                    q_cat, bt_rows, pos, valid, kv_len,
+                    jnp.asarray(layer, jnp.int32)]
         if scale_new:
             specs += [rep, rep]
             operands += [scale_new["ckv_scale"], scale_new["krope_scale"]]
@@ -1317,12 +1332,11 @@ def mla_decode_paged(
             out_specs=(rep, pspec, pspec),
         )(*operands)
     else:
-        ckv_pages = write_pages(cache["ckv_pages"], bt_rows, pos, ckv_q,
-                                cap, valid)
-        krope_pages = write_pages(cache["krope_pages"], bt_rows, pos,
-                                  kr_q, cap, valid)
+        got = _write_rows(cache, {"ckv_pages": ckv_q, "krope_pages": kr_q},
+                          layer, bt_rows, pos, cap, valid, tok=0)
+        ckv_pages, krope_pages = got["ckv_pages"], got["krope_pages"]
         out_lat = fusemax_mla_decode_paged(
-            q_cat, ckv_pages, krope_pages, bt_rows, kv_len,
+            q_cat, ckv_pages, krope_pages, bt_rows, kv_len, layer=layer,
             scale=scale, softcap=cfg.attn_softcap,
             ckv_scale=scale_new.get("ckv_scale"),
             krope_scale=scale_new.get("krope_scale"),
@@ -1339,10 +1353,11 @@ def mla_decode_paged(
 def mla_verify_paged(
     p, x: jnp.ndarray, cache: dict, bt_rows: jnp.ndarray,
     kv_len: jnp.ndarray, span: jnp.ndarray,
-    cfg: ModelConfig, spec: LayerSpec, rt: Runtime,
+    cfg: ModelConfig, spec: LayerSpec, rt: Runtime, layer,
 ) -> tuple[jnp.ndarray, dict]:
     """Paged latent-space verify: the P-chain analogue of
-    :func:`mla_decode_paged` (chain contract in :func:`gqa_verify`;
+    :func:`mla_decode_paged`, as layer ``layer`` of the stacked pools
+    (chain contract in :func:`gqa_verify`;
     chain latents land in the slot's scratch draft pages through the
     block table).  Unsharded only — the engine gates speculation off
     under a device mesh."""
@@ -1351,7 +1366,7 @@ def mla_verify_paged(
     dt = x.dtype
     pos = (kv_len - 1)[:, None] + jnp.arange(pq)[None]   # [B, P]
     q_nope, q_rope, ckv_new, krope_new = _mla_qkv_latent(p, x, cfg, pos)
-    page_size = cache["ckv_pages"].shape[1]
+    page_size = cache["ckv_pages"].shape[2]
     cap = bt_rows.shape[1] * page_size
     valid = (jnp.arange(pq)[None] < span[:, None]) & (kv_len > 0)[:, None]
     scale = 1.0 / math.sqrt(m.nope_dim + m.rope_dim)
@@ -1360,22 +1375,20 @@ def mla_verify_paged(
     q_cat = jnp.concatenate([q_eff, q_rope], axis=-1)    # [B,H,P,r+rd]
 
     ckv_q, ckv_s, kr_q, kr_s = _mla_quant_new(cache, ckv_new, krope_new)
-    scale_new = {} if ckv_s is None else _mla_write_scales(
-        cache, bt_rows, pos, ckv_s, kr_s, cap, valid)
-    ckv_pages = write_pages(cache["ckv_pages"], bt_rows, pos, ckv_q,
-                            cap, valid)
-    krope_pages = write_pages(cache["krope_pages"], bt_rows, pos,
-                              kr_q, cap, valid)
+    new = {"ckv_pages": ckv_q, "krope_pages": kr_q}
+    if ckv_s is not None:
+        new.update(ckv_scale=ckv_s, krope_scale=kr_s)
+    pools = _write_rows(cache, new, layer, bt_rows, pos, cap, valid, tok=0)
     out_lat = fusemax_mla_decode_paged(
-        q_cat, ckv_pages, krope_pages, bt_rows, kv_len,
+        q_cat, pools["ckv_pages"], pools["krope_pages"], bt_rows, kv_len,
+        layer=layer,
         scale=scale, softcap=cfg.attn_softcap,
-        ckv_scale=scale_new.get("ckv_scale"),
-        krope_scale=scale_new.get("krope_scale"),
+        ckv_scale=pools.get("ckv_scale"),
+        krope_scale=pools.get("krope_scale"),
         impl=rt.attn_impl,
         exp_impl=rt.exp_impl,
         interpret=rt.interpret,
     )                                                    # [B,H,P,r]
     out = jnp.einsum("bhsr,rhe->bhse", out_lat, p["w_uv"].astype(dt))
     y = jnp.einsum("bhse,hed->bsd", out, p["wo"].astype(dt))
-    return y, {"ckv_pages": ckv_pages, "krope_pages": krope_pages,
-               **scale_new}
+    return y, pools

@@ -127,10 +127,14 @@ def layer_init_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
 
 
 def layer_decode(p, x, cache, kv_len, cfg: ModelConfig, spec: LayerSpec,
-                 rt: Runtime, block_tables: Optional[dict] = None):
+                 rt: Runtime, block_tables: Optional[dict] = None,
+                 layer=None):
     """One-token decode. x: [B, 1, d]; kv_len includes the current token.
     With ``block_tables`` (paged layout — {"full"/"w<N>": [B, W] int32})
-    attention layers read/write the page pool through their table."""
+    attention layers read/write the page pools through their table:
+    ``cache["attn"]`` then holds the pools of the whole run, stacked over
+    its layers, and this layer is ``layer`` of them (see
+    :func:`_layer_loop`)."""
     h = apply_norm(p["ln1"], x, cfg.norm)
     parts = []
     new_cache = dict(cache)
@@ -139,7 +143,7 @@ def layer_decode(p, x, cache, kv_len, cfg: ModelConfig, spec: LayerSpec,
             y, new_cache["attn"] = attn_mod.gqa_decode_paged(
                 p["attn"], h, cache["attn"],
                 block_tables[attn_mod.paged_cache_key(spec)], kv_len, cfg,
-                spec, rt)
+                spec, rt, layer)
         else:
             y, new_cache["attn"] = attn_mod.gqa_decode(
                 p["attn"], h, cache["attn"], kv_len, cfg, spec, rt)
@@ -148,7 +152,7 @@ def layer_decode(p, x, cache, kv_len, cfg: ModelConfig, spec: LayerSpec,
         if block_tables is not None:
             y, new_cache["attn"] = attn_mod.mla_decode_paged(
                 p["attn"], h, cache["attn"], block_tables["full"], kv_len,
-                cfg, spec, rt)
+                cfg, spec, rt, layer)
         else:
             y, new_cache["attn"] = attn_mod.mla_decode(
                 p["attn"], h, cache["attn"], kv_len, cfg, spec, rt)
@@ -183,9 +187,10 @@ def layer_decode(p, x, cache, kv_len, cfg: ModelConfig, spec: LayerSpec,
 
 def layer_verify(p, x, cache, kv_len, span, cfg: ModelConfig,
                  spec: LayerSpec, rt: Runtime,
-                 block_tables: Optional[dict] = None):
+                 block_tables: Optional[dict] = None, layer=None):
     """P-position speculative verify through one layer (the chain
-    analogue of :func:`layer_decode`; x: [B, P, d]).  Attention-only:
+    analogue of :func:`layer_decode`, same ``layer`` contract; x:
+    [B, P, d]).  Attention-only:
     SSM layers carry recurrent state that cannot be rolled back by page
     surgery, so the engine never enables speculation for them."""
     if spec.ssm is not None:
@@ -197,7 +202,7 @@ def layer_verify(p, x, cache, kv_len, span, cfg: ModelConfig,
             y, new_cache["attn"] = attn_mod.gqa_verify_paged(
                 p["attn"], h, cache["attn"],
                 block_tables[attn_mod.paged_cache_key(spec)], kv_len, span,
-                cfg, spec, rt)
+                cfg, spec, rt, layer)
         else:
             y, new_cache["attn"] = attn_mod.gqa_verify(
                 p["attn"], h, cache["attn"], kv_len, span, cfg, spec, rt)
@@ -205,7 +210,7 @@ def layer_verify(p, x, cache, kv_len, span, cfg: ModelConfig,
         if block_tables is not None:
             y, new_cache["attn"] = attn_mod.mla_verify_paged(
                 p["attn"], h, cache["attn"], block_tables["full"], kv_len,
-                span, cfg, spec, rt)
+                span, cfg, spec, rt, layer)
         else:
             y, new_cache["attn"] = attn_mod.mla_verify(
                 p["attn"], h, cache["attn"], kv_len, span, cfg, spec, rt)
@@ -426,11 +431,13 @@ def layer_init_paged_cache(cfg: ModelConfig, spec: LayerSpec, slots: int,
 def init_paged_cache(cfg: ModelConfig, slots: int, num_pages: dict,
                      page_size: int, dtype, kv_dtype: str | None = None):
     """Per-run paged caches mirroring :func:`init_cache`'s tree structure
-    (stacked over repeats), so the scan/unroll machinery and donation work
-    unchanged.  Every layer owns its own page storage; the block tables
-    (one per capacity class, shared by all layers of the class) are managed
-    host-side by :class:`repro.serving.kv_cache.PagedKVCache` and passed
-    per dispatch.
+    (stacked over repeats).  The decode and verify layer loops carry each
+    pattern position's stacked pools whole and update a layer's rows in
+    place (:func:`_layer_loop`); prefill scans over them per layer like
+    the dense caches.  Every layer owns its own page storage; the block
+    tables (one per capacity class, shared by all layers of the class) are
+    managed host-side by :class:`repro.serving.kv_cache.PagedKVCache` and
+    passed per dispatch.
 
     Device sharding note: arrays are created unplaced; ``PagedKVCache``
     device_puts them with ``sharding.paged_cache_shardings`` when the
@@ -518,6 +525,72 @@ def cache_axes(cfg: ModelConfig):
     return axes
 
 
+def _layer_loop(cfg: ModelConfig, params, x, caches, rt: Runtime,
+                paged: bool, step):
+    """Run ``x`` through every layer for one decode or verify step:
+    ``step(p, h, cache, spec, layer) -> (h, new_cache)`` is one layer.
+
+    Dense caches (``paged`` False) scan each stacked run with its per-layer
+    cache slices as the scan's xs/ys.  Paged, each pattern position's page
+    pools (``cache["attn"]``, stacked over the run's repeats) ride in the
+    loop *carry* instead, and every layer writes and reads its rows of
+    them at index ``layer`` in place: slicing a layer's pool out as an xs
+    and stacking the updated one as a ys would copy every layer's pool per
+    step.  Everything else (params, SSM state) stays xs/ys.  A run of one
+    repeat holds unstacked pools, the ``L = 1`` case (``pool[None]``,
+    layer 0)."""
+    def split(c):
+        if not paged or "attn" not in c:
+            return None, c
+        return c["attn"], {k: v for k, v in c.items() if k != "attn"}
+
+    def call(p, h, pool, rest, spec, layer):
+        c = rest if pool is None else {**rest, "attn": pool}
+        h, c = step(p, h, c, spec, layer)
+        pool, rest = split(c)
+        return h, pool, rest
+
+    new_caches = []
+    for (pattern, reps), p_run, cache in zip(cfg.runs(), params["runs"],
+                                             caches):
+        pools, rests = map(list, zip(*[split(c) for c in cache]))
+        if reps == 1:
+            for j, (spec_j, p_j) in enumerate(zip(pattern, p_run)):
+                lifted = jax.tree.map(lambda a: a[None], pools[j])
+                x, pool, rests[j] = call(p_j, x, lifted, rests[j], spec_j, 0)
+                pools[j] = jax.tree.map(lambda a: a[0], pool)
+        elif rt.unroll_runs:
+            outs = [[] for _ in pattern]
+            for i in range(reps):
+                for j, (spec_j, p_j, rest) in enumerate(
+                        zip(pattern, p_run, rests)):
+                    p_i = jax.tree.map(lambda a: a[i], p_j)
+                    r_i = jax.tree.map(lambda a: a[i], rest)
+                    x, pools[j], r_i = call(p_i, x, pools[j], r_i, spec_j, i)
+                    outs[j].append(r_i)
+            rests = [jax.tree.map(lambda *xs: jnp.stack(xs), *o)
+                     for o in outs]
+        else:
+            def body(carry, xs):
+                h, pools = carry
+                i, ps, rests = xs
+                pools_out, rests_out = [], []
+                for spec_j, p_j, pool, rest in zip(pattern, ps, pools,
+                                                   rests):
+                    h, pool, rest = call(p_j, h, pool, rest, spec_j, i)
+                    pools_out.append(pool)
+                    rests_out.append(rest)
+                return (h, tuple(pools_out)), tuple(rests_out)
+
+            (x, pools), rests = jax.lax.scan(
+                body, (x, tuple(pools)),
+                (jnp.arange(reps, dtype=jnp.int32), tuple(p_run),
+                 tuple(rests)))
+        new_caches.append([rest if pool is None else {**rest, "attn": pool}
+                           for pool, rest in zip(pools, rests)])
+    return x, new_caches
+
+
 def decode_step(cfg: ModelConfig, params, token_or_embed, caches,
                 kv_len: jnp.ndarray, rt: Runtime = Runtime(),
                 block_tables: Optional[dict] = None):
@@ -526,48 +599,19 @@ def decode_step(cfg: ModelConfig, params, token_or_embed, caches,
     token_or_embed: [B, 1] int tokens or [B, 1, d] embeddings.
     kv_len: [B] sequence length *including* the current token.
     ``block_tables`` selects the paged cache layout (see
-    :func:`layer_decode`); None decodes against dense caches.
+    :func:`layer_decode`; the layer loop carries the page pools, see
+    :func:`_layer_loop`); None decodes against dense caches.
     Returns (logits [B, vocab], new_caches).
     """
     batch = {"inputs": token_or_embed}
     x = _embed_inputs(cfg, params, batch, rt)
-    new_caches = []
-    for (pattern, reps), p_run, cache in zip(cfg.runs(), params["runs"],
-                                             caches):
-        if reps == 1:
-            cs = []
-            for spec_j, p_j, c_j in zip(pattern, p_run, cache):
-                x, c_new = layer_decode(p_j, x, c_j, kv_len, cfg, spec_j,
-                                        rt, block_tables)
-                cs.append(c_new)
-            new_caches.append(cs)
-            continue
 
-        if rt.unroll_runs:
-            outs = [[] for _ in pattern]
-            for i in range(reps):
-                for j, (spec_j, p_j, c_j) in enumerate(
-                        zip(pattern, p_run, cache)):
-                    p_i = jax.tree.map(lambda a: a[i], p_j)
-                    c_i = jax.tree.map(lambda a: a[i], c_j)
-                    x, c_new = layer_decode(p_i, x, c_i, kv_len, cfg,
-                                            spec_j, rt, block_tables)
-                    outs[j].append(c_new)
-            new_caches.append([
-                jax.tree.map(lambda *xs: jnp.stack(xs), *o) for o in outs])
-            continue
+    def step(p, h, c, spec, layer):
+        return layer_decode(p, h, c, kv_len, cfg, spec, rt, block_tables,
+                            layer)
 
-        def body(h, pc):
-            ps, cs_in = pc
-            cs_out = []
-            for spec_j, p_j, c_j in zip(pattern, ps, cs_in):
-                h, c_new = layer_decode(p_j, h, c_j, kv_len, cfg, spec_j,
-                                        rt, block_tables)
-                cs_out.append(c_new)
-            return h, tuple(cs_out)
-
-        x, c = jax.lax.scan(body, x, (tuple(p_run), tuple(cache)))
-        new_caches.append(list(c))
+    x, new_caches = _layer_loop(cfg, params, x, caches, rt,
+                                block_tables is not None, step)
     x = apply_norm(params["final_norm"], x, cfg.norm)
     head = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = unembed(head, x[:, 0])
@@ -598,43 +642,13 @@ def verify_step(cfg: ModelConfig, params, tokens, caches,
     ``tests/test_speculative.py``)."""
     batch = {"inputs": tokens}
     x = _embed_inputs(cfg, params, batch, rt)
-    new_caches = []
-    for (pattern, reps), p_run, cache in zip(cfg.runs(), params["runs"],
-                                             caches):
-        if reps == 1:
-            cs = []
-            for spec_j, p_j, c_j in zip(pattern, p_run, cache):
-                x, c_new = layer_verify(p_j, x, c_j, kv_len, span, cfg,
-                                        spec_j, rt, block_tables)
-                cs.append(c_new)
-            new_caches.append(cs)
-            continue
 
-        if rt.unroll_runs:
-            outs = [[] for _ in pattern]
-            for i in range(reps):
-                for j, (spec_j, p_j, c_j) in enumerate(
-                        zip(pattern, p_run, cache)):
-                    p_i = jax.tree.map(lambda a: a[i], p_j)
-                    c_i = jax.tree.map(lambda a: a[i], c_j)
-                    x, c_new = layer_verify(p_i, x, c_i, kv_len, span, cfg,
-                                            spec_j, rt, block_tables)
-                    outs[j].append(c_new)
-            new_caches.append([
-                jax.tree.map(lambda *xs: jnp.stack(xs), *o) for o in outs])
-            continue
+    def step(p, h, c, spec, layer):
+        return layer_verify(p, h, c, kv_len, span, cfg, spec, rt,
+                            block_tables, layer)
 
-        def body(h, pc):
-            ps, cs_in = pc
-            cs_out = []
-            for spec_j, p_j, c_j in zip(pattern, ps, cs_in):
-                h, c_new = layer_verify(p_j, h, c_j, kv_len, span, cfg,
-                                        spec_j, rt, block_tables)
-                cs_out.append(c_new)
-            return h, tuple(cs_out)
-
-        x, c = jax.lax.scan(body, x, (tuple(p_run), tuple(cache)))
-        new_caches.append(list(c))
+    x, new_caches = _layer_loop(cfg, params, x, caches, rt,
+                                block_tables is not None, step)
     x = apply_norm(params["final_norm"], x, cfg.norm)
     head = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = unembed(head, x)                            # [B, P, vocab]
@@ -979,7 +993,10 @@ def decode_loop(cfg: ModelConfig, params, caches, kv_len, last_logits,
 
     ``block_tables`` (paged layout) is loop-invariant: the engine reserves
     pages covering every slot's worst-case growth for the chunk *before*
-    dispatching, so no allocation can be needed mid-loop.
+    dispatching, so no allocation can be needed mid-loop.  The loop
+    carries the whole cache tree; on the paged layout each step's layer
+    loop carries the stacked page pools in turn and writes each layer's
+    new rows into them in place, so no step copies a pool.
     """
     b = kv_len.shape[0]
     toks0 = jnp.zeros((n_steps, b), jnp.int32)

@@ -1,6 +1,8 @@
 """Paged KV-cache subsystem: free-list allocator, block-table growth,
 paged↔dense greedy equivalence (mixed lengths, ring eviction, preemption),
 and the paged split-K Pallas kernel vs the reference."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -206,6 +208,162 @@ def test_paged_pallas_decode_splits_and_softcap():
                                block_k=8)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def _quantized_pool(key, shape, qdt):
+    """Codes [L, P, Hkv, ps, E] and fp16 scales [L, P, Hkv, ps] of random
+    K/V, as a quantized pool stores them."""
+    from repro.model.attention import quantize_kv
+    return quantize_kv(jax.random.normal(key, shape, jnp.float32), qdt)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+@pytest.mark.parametrize("pool", ["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("capacity", [None, 20])
+def test_paged_decode_reads_one_layer_of_stacked_pools(impl, pool,
+                                                       capacity):
+    """The paged kernel handed pools stacked over layers [L, P, Hkv, ps, E]
+    and a (traced) layer index gives, for every layer, exactly the output
+    it gives on that layer's pool alone — with sentinel page ids in the
+    table, quantized pools (scales stacked alike) and a ring capacity
+    that truncates the logical view."""
+    n_layers, b, hq, hkv, e = 3, 2, 4, 2, 16
+    n_pages, ps = 10, 8
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (b, hq, 1, e), jnp.float32)
+    shape = (n_layers, n_pages, hkv, ps, e)
+    if pool == "bf16":
+        k = jax.random.normal(ks[1], shape, jnp.bfloat16)
+        v = jax.random.normal(ks[2], shape, jnp.bfloat16)
+        k_s = v_s = None
+    else:
+        qdt = jnp.int8 if pool == "int8" else jnp.float8_e4m3fn
+        k, k_s = _quantized_pool(ks[1], shape, qdt)
+        v, v_s = _quantized_pool(ks[2], shape, qdt)
+    sentinel = n_pages
+    bt = jnp.asarray([[3, 9, sentinel, sentinel],
+                      [2, 5, 0, sentinel]], jnp.int32)
+    kv_len = jnp.asarray([13, 20], jnp.int32)
+    if capacity is not None:
+        kv_len = jnp.minimum(kv_len, capacity)
+
+    def run(k, v, k_s, v_s, layer):
+        return fusemax_decode_paged(q, k, v, bt, kv_len, layer=layer,
+                                    capacity=capacity, impl=impl,
+                                    k_scale=k_s, v_scale=v_s)
+
+    stacked = jax.jit(run)
+    one = jax.jit(lambda k, v, k_s, v_s: run(k, v, k_s, v_s, 0))
+    pick = lambda a, l: None if a is None else a[l]
+    outs = []
+    for l in range(n_layers):
+        got = stacked(k, v, k_s, v_s, jnp.int32(l))
+        want = one(k[l], v[l], pick(k_s, l), pick(v_s, l))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=f"layer {l}")
+        outs.append(np.asarray(got))
+    assert not np.array_equal(outs[0], outs[1])     # the layers differ
+
+
+@pytest.mark.parametrize("tok,shape,window", [
+    (1, (2, 6, 3, 4, 5), None),      # GQA pages [L, P, Hkv, ps, dh]
+    (1, (2, 6, 3, 4), None),         # GQA scales [L, P, Hkv, ps]
+    (0, (2, 6, 4, 5), None),         # MLA latent pages [L, P, ps, r]
+    (0, (2, 6, 4, 5), 8),            # ... on a ring of 8 tokens
+])
+def test_write_page_rows_matches_the_scatter(tok, shape, window):
+    """The in-place row writes of the decode step change a stacked pool
+    exactly as the page scatter changes that layer's pool: the same
+    rows, ring wrap included, and nothing where a row is not valid or its
+    table entry is the sentinel page (a clamped write would land on the
+    last real page)."""
+    from repro.model.attention import (
+        write_kv_pages, write_page_rows, write_pages)
+    layer = 1
+    n_pages, ps = shape[1], shape[2 + tok]
+    pool = jax.random.normal(jax.random.PRNGKey(0), shape, jnp.float32)
+    bt = jnp.asarray([[4, 1, 5], [2, 6, 6], [0, 3, 6]], jnp.int32)
+    # slot 1 sits on the sentinel page 6, slot 2 is not valid
+    positions = jnp.asarray([[5, 6], [9, 10], [1, 2]], jnp.int32)
+    valid = jnp.asarray([[True, True], [True, True], [False, False]])
+    cap = window if window is not None else bt.shape[1] * ps
+    new_shape = (3, *shape[2:2 + tok], 2, *shape[3 + tok:])
+    values = jax.random.normal(jax.random.PRNGKey(1), new_shape)
+
+    got = jax.jit(lambda pool, lyr: write_page_rows(
+        pool, lyr, bt, positions, values, cap, valid, tok=tok))(
+            pool, jnp.int32(layer))
+    scatter = write_kv_pages if tok == 1 else write_pages
+    want = pool.at[layer].set(
+        scatter(pool[layer], bt, positions, values, cap, valid))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.array_equal(np.asarray(got), np.asarray(pool))
+
+
+@pytest.mark.parametrize("arch,unroll", [
+    ("gemma2-9b-smoke", False), ("stablelm-1.6b-smoke", False),
+    ("stablelm-1.6b-smoke", True), ("hymba-1.5b-smoke", False),
+    ("deepseek-v3-671b-smoke", False)])
+def test_paged_decode_loop_matches_per_token_steps(arch, unroll):
+    """``decode_loop`` over the paged layout (pools carried through the
+    layer loop, written in place) gives the same tokens and the same final
+    pools and state as per-token ``decode_step`` calls: a local/global
+    pattern (gemma2), one pattern position (stablelm, also with the layer
+    loop unrolled), runs of one repeat with SSM state beside the pools
+    (hymba), and paged MLA (deepseek).  Slot 1 runs out of budget
+    mid-loop, slot 2 is free (kv_len 0, sentinel table row)."""
+    cfg = get_config(arch)
+    rt = dataclasses.replace(RT, unroll_runs=unroll)
+    params, _ = tf.init(cfg, jax.random.PRNGKey(0), rt)
+    slots, max_len, ps, n_steps = 3, 64, 8, 4
+    keys = {"full": max_len}
+    keys.update({f"w{s.window}": s.window for s in cfg.layer_specs()
+                 if s.window is not None})
+    widths = {k: -(-cap // ps) for k, cap in keys.items()}
+    num_pages = {k: 2 * w for k, w in widths.items()}
+    caches = tf.init_paged_cache(cfg, slots, num_pages, ps, jnp.float32)
+    # slots 0 and 1 own interleaved pages; slot 2 holds only the sentinel
+    tables = {k: jnp.asarray([list(range(0, 2 * w, 2)),
+                              list(range(1, 2 * w, 2)),
+                              [num_pages[k]] * w], jnp.int32)
+              for k, w in widths.items()}
+
+    lens = [11, 6]
+    prompts = np.random.default_rng(0).integers(0, cfg.vocab, (2, 11))
+    logits, caches = tf.prefill(
+        cfg, params, {"inputs": jnp.asarray(prompts, jnp.int32)}, caches,
+        rt, true_len=jnp.asarray(lens, jnp.int32),
+        block_tables={k: t[:2] for k, t in tables.items()},
+        slot_ids=jnp.asarray([0, 1], jnp.int32))
+    logits = jnp.concatenate(
+        [logits.astype(jnp.float32), jnp.zeros((1, logits.shape[-1]))])
+    kv_len = jnp.asarray(lens + [0], jnp.int32)
+    remaining = jnp.asarray([n_steps, 2, 0], jnp.int32)
+    key = jax.random.PRNGKey(1)
+
+    loop = jax.jit(lambda c, kv, lg, rem: tf.decode_loop(
+        cfg, params, c, kv, lg, rem, key, n_steps=n_steps, rt=rt,
+        block_tables=tables))
+    toks, loop_caches, loop_kv, _, _, _, steps = loop(
+        caches, kv_len, logits, remaining)
+
+    step = jax.jit(lambda c, t, kv: tf.decode_step(
+        cfg, params, t, c, kv, rt, block_tables=tables))
+    want = []
+    for _ in range(n_steps):
+        active = remaining > 0
+        nxt = jnp.where(active, jnp.argmax(logits, -1), 0).astype(jnp.int32)
+        want.append(np.asarray(nxt))
+        kv_len = kv_len + active
+        new, caches = step(caches, nxt[:, None], kv_len)
+        logits = jnp.where(active[:, None], new.astype(jnp.float32), logits)
+        remaining = remaining - active
+    assert int(steps) == n_steps
+    np.testing.assert_array_equal(np.asarray(toks), np.stack(want))
+    np.testing.assert_array_equal(np.asarray(loop_kv), np.asarray(kv_len))
+    for got, ref in zip(jax.tree.leaves(loop_caches),
+                        jax.tree.leaves(caches)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 # ---------------------------------------------------------------------------

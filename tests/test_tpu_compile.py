@@ -10,6 +10,8 @@ program text as ``tpu_custom_call``.
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file loads the TPU compiler library.
 """
+import re
+
 import pytest
 
 import jax
@@ -125,10 +127,14 @@ def test_dense_decode_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_stablelm_decode_loop_fits_one_chip(one_chip):
+STABLELM_SLOTS, STABLELM_MAX_LEN = 8, 1024
+
+
+@pytest.fixture(scope="module")
+def stablelm_loop(one_chip):
     """One fused decode dispatch of stablelm-1.6b in bf16 through the
-    Pallas kernels, at ``chip_smoke.py``'s pool (8 slots x 1024 tokens):
-    the compiler fits it in one v5e's HBM (it raises otherwise)."""
+    Pallas kernels, at ``chip_smoke.py``'s pool (8 slots x 1024 tokens),
+    compiled for the described chip."""
     from repro.configs import get_config
     from repro.model import transformer as tf
     from repro.model.layers import Runtime
@@ -136,7 +142,7 @@ def test_stablelm_decode_loop_fits_one_chip(one_chip):
     cfg = get_config("stablelm-1.6b")
     rt = Runtime(attn_impl="pallas", interpret=False,
                  param_dtype=jnp.bfloat16, activation_dtype=jnp.bfloat16)
-    slots, max_len = 8, 1024
+    slots, max_len = STABLELM_SLOTS, STABLELM_MAX_LEN
     place = lambda tree: jax.tree.map(
         lambda a: _shape(one_chip, a.shape, a.dtype), tree)
     params = place(jax.eval_shape(lambda k: tf.init(cfg, k, rt)[0],
@@ -150,16 +156,107 @@ def test_stablelm_decode_loop_fits_one_chip(one_chip):
                               remaining, key, n_steps=16, rt=rt,
                               block_tables=tables)
 
-    compiled = jax.jit(loop, donate_argnums=(1, 2, 3, 4, 5)).lower(
+    return jax.jit(loop, donate_argnums=(1, 2, 3, 4, 5)).lower(
         params, caches, s((slots,), jnp.int32),
         s((slots, cfg.vocab), jnp.float32), s((slots,), jnp.int32),
         s((2,), jnp.uint32), {"full": s((slots, max_len // PS), jnp.int32)},
     ).compile()
+
+
+def test_stablelm_decode_loop_fits_one_chip(stablelm_loop):
+    """The compiler fits the decode dispatch in one v5e's HBM (it raises
+    otherwise)."""
+    compiled = stablelm_loop
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             - mem.alias_size_in_bytes + mem.output_size_in_bytes)
     assert live < 16e9, mem
+
+
+_COMP = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)$")
+_CALLED = re.compile(r"(?:calls|body|condition|to_apply|true_computation|"
+                     r"false_computation)=%([\w.\-]+)")
+_DIMS = re.compile(r"[a-z]\w*\[([\d,]*)\]")
+
+
+def _hlo_computations(text: str) -> dict:
+    """Computation name → {instruction name: (dims, opcode, operands,
+    called computations)} of an HLO module's text; ``dims`` are those of
+    the result (of its first element, for a tuple)."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = _COMP.match(line)
+        if head:
+            cur = comps.setdefault(head.group(1), {})
+            continue
+        m = _INSTR.match(line) if cur is not None else None
+        if m is None:
+            continue
+        name, shape, opcode, rest = m.groups()
+        dims = _DIMS.search(shape)
+        dims = tuple(int(d) for d in dims.group(1).split(",") if d) \
+            if dims else None
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        called = _CALLED.findall(rest)
+        called += re.findall(r"%([\w.\-]+)", "".join(
+            re.findall(r"branch_computations=\{([^}]*)\}", rest)))
+        cur[name] = (dims, opcode, operands, called)
+    return comps
+
+
+def _in_loops(comps: dict) -> set:
+    """Every computation a ``while`` body or condition reaches: the
+    decode loop, the layer loop inside it, and what they call or fuse."""
+    todo = [c for instrs in comps.values()
+            for _, op, _, called in instrs.values() if op == "while"
+            for c in called]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        todo += [d for *_, called in comps[c].values() for d in called]
+    return seen
+
+
+def test_stablelm_decode_loop_updates_pools_in_place(stablelm_loop):
+    """Inside the decode and layer loops the stacked K/V pools are only
+    updated in place, a few rows per layer, and read by the kernel: no
+    whole layer's pool is ever materialised there (sliced out, scattered
+    into, or stacked back into a fresh pool), no copy of a whole pool
+    runs, and no update writes a whole layer.  The pool's layout copies at
+    the program's entry and exit, outside the loops, are allowed.  With
+    none of those pool-sized buffers, the temporaries fall to about the
+    lane-padded pools themselves."""
+    from repro.configs import get_config
+
+    cfg = get_config("stablelm-1.6b")
+    n_pages = STABLELM_SLOTS * STABLELM_MAX_LEN // PS
+    layer = (n_pages, cfg.n_kv_heads, PS, cfg.dh)
+    pool = (cfg.n_layers, *layer)
+    whole_layer = {layer, (1, *layer)}
+
+    comps = _hlo_computations(stablelm_loop.as_text())
+    loops = _in_loops(comps)
+    assert loops
+    bad = []
+    for c in loops:
+        instrs = comps[c]
+        for name, (dims, op, operands, _) in instrs.items():
+            if dims in whole_layer:
+                bad.append((c, name, op, dims))
+            elif dims == pool and op in ("copy", "copy-start", "copy-done",
+                                         "dynamic-slice"):
+                bad.append((c, name, op, dims))
+            elif op == "dynamic-update-slice":
+                update = instrs.get(operands[1], (None,))[0]
+                if update in whole_layer or update == pool:
+                    bad.append((c, name, op, update))
+    assert not bad, bad
+    assert stablelm_loop.memory_analysis().temp_size_in_bytes < 5 * 2**30
 
 
 @pytest.mark.parametrize("kernel", ["fusemax_decode_paged",
