@@ -5,7 +5,10 @@ through ``submit_async`` and driven by this file's pump loop.  From the
 program the benchmark takes only that engine, its counters and cache
 arrays, and the names of its compiled programs; the traffic, the window
 arithmetic, the trace reduction, the work counts, the peaks and the
-reference that decides ``correct`` are the benchmark's own.
+reference that decides ``correct`` are the benchmark's own.  What knows
+the model's shape (the program's config, the reference's layers, the K/V
+element count, the work counts, the kernel names) is the architecture
+module the configuration names (``cell.arch``, ``bench/arch/<arch>.py``).
 
 Phases (host spans ``bench.*`` mark each on the profiler's clock):
 
@@ -42,7 +45,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from bench import reference, registry, traffic, window, work
+from bench import reference, registry, traffic, window
 
 #: salt for the seed of the warm-up trace, so it never repeats the window's
 WARM_SALT = 0x5EED
@@ -55,30 +58,6 @@ DTYPE_BITS = {"float32": 32, "bfloat16": 16, "float16": 16}
 
 def log(msg: str) -> None:
     print(msg, flush=True)
-
-
-# -- the program's side ----------------------------------------------------
-
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    heads = config["num_attention_heads"]
-    return ModelConfig(
-        name=config.get("model_type", "bench"),
-        n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        n_heads=heads,
-        n_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"],
-        vocab=config["vocab_size"],
-        head_dim=config.get("head_dim") or config["hidden_size"] // heads,
-        family="dense",
-        norm=config["norm"],
-        rope_theta=float(config["rope_theta"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        source=config["source"],
-    )
 
 
 def next_pow2(n: int) -> int:
@@ -177,7 +156,7 @@ def build(cell: registry.Cell, seed: int, opts: Options):
             super()._prefill_quantum_dispatch(rid)
 
     e = dict(cell.config["engine"], **(opts.engine or {}))
-    cfg = model_config(cell.config)
+    cfg = cell.arch.model_config(cell.config)
     rt = Runtime(attn_impl=e["attn_impl"], interpret=opts.interpret,
                  param_dtype=jnp.bfloat16, activation_dtype=jnp.bfloat16)
     with span("bench.weights"):
@@ -307,7 +286,7 @@ def sample_for_check(reqs, seed: int, k: int = CHECK_REQUESTS):
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def check(config: dict, seed: int, sample, max_len: int,
+def check(arch, config: dict, seed: int, sample, max_len: int,
           precisions=("f32",)) -> Dict[str, np.ndarray]:
     """Reference gaps of every served token of ``sample``: for each
     precision, how far the reference's logit of the token that precision
@@ -318,31 +297,29 @@ def check(config: dict, seed: int, sample, max_len: int,
         seqs.append(s)
         poss.append(p)
         served.append(g)
-    ref = reference.logits_at(config, seed, seqs, poss, max_len, "f32")
+    ref = reference.logits_at(arch, config, seed, seqs, poss, max_len,
+                              "f32")
     out = {"program": np.concatenate(
         [reference.served_gaps(l, g) for l, g in zip(ref, served)])}
     for prec in precisions:
         if prec == "f32":
             continue
-        ctl = reference.logits_at(config, seed, seqs, poss, max_len, prec)
+        ctl = reference.logits_at(arch, config, seed, seqs, poss, max_len,
+                                  prec)
         out[prec] = np.concatenate(
             [reference.served_gaps(l, c.argmax(-1)) for l, c in
              zip(ref, ctl)])
     return out
 
 
-def kv_bits(eng, config: dict) -> float:
+def kv_bits(eng, cell: registry.Cell) -> float:
     """Bits the engine's cache arrays hold per element of the K/V pool the
-    configuration describes: every byte of ``engine.caches`` over pages x
-    page size x layers x (K, V) x kv heads x head size."""
+    configuration describes: every byte of ``engine.caches`` over the
+    architecture's count of the pool's elements."""
     import jax
 
-    e = config["engine"]
-    heads = config["num_attention_heads"]
-    dh = config.get("head_dim") or config["hidden_size"] // heads
-    elems = (e["num_pages"] * e["page_size"] * config["num_hidden_layers"]
-             * 2 * config["num_key_value_heads"] * dh)
-    return 8.0 * sum(x.nbytes for x in jax.tree.leaves(eng.caches)) / elems
+    return 8.0 * sum(x.nbytes for x in jax.tree.leaves(eng.caches)) \
+        / cell.arch.kv_elements(cell.config)
 
 
 def judge(cell: registry.Cell, gaps: np.ndarray, m: dict, bits: float,
@@ -485,7 +462,7 @@ def measure(s: Session, seed: int, seconds: float, trace: bool,
             f"prefilled in the window where the engine counted "
             f"{delta['tokens_prefilled']}: its hook on the engine's prefill "
             f"quantum (bench/harness.py, build) no longer sees every one")
-    bits = kv_bits(eng, cell.config)
+    bits = kv_bits(eng, cell)
 
     # memory: what the runtime saw, and what the decode program asks for
     mem = s.dev.memory_stats() or {}
@@ -499,14 +476,16 @@ def measure(s: Session, seed: int, seconds: float, trace: bool,
         from bench import trace as trace_mod
 
         t = time.perf_counter()
-        red = trace_mod.reduce(trace_mod.load(_xplane(trace_dir)))
+        red = trace_mod.reduce(trace_mod.load(_xplane(trace_dir)),
+                               cell.arch.KERNELS)
         shutil.rmtree(trace_dir, ignore_errors=True)
         log(f"trace reduced in {time.perf_counter() - t:.1f}s: programs "
             f"{red.programs} over {red.program_runs} runs, kernels "
             f"{red.kernels}")
         record = {
             "trace": red, "stats": delta, "slots": e["slots"],
-            "shapes": work.Shapes.from_config(cell.config), "peak": s.peak,
+            "shapes": cell.arch.Shapes.from_config(cell.config),
+            "peak": s.peak,
             "decode_ctx": decode_contexts(drv.reqs, t0, t_end),
             "prefills": prefills,
             "decode_hbm_bytes": decode_bytes,
@@ -521,7 +500,8 @@ def measure(s: Session, seed: int, seconds: float, trace: bool,
         gc.collect()
     t = time.perf_counter()
     precisions = ("f32", "fp8") if opts.control else ("f32",)
-    read = check(cell.config, seed, sample, e["max_len"], precisions) \
+    read = check(cell.arch, cell.config, seed, sample, e["max_len"],
+                 precisions) \
         if sample else {"program": np.array([np.inf])}
     # the control, where one is asked for, stands in the program's place
     gaps = read.get("fp8", read["program"]) if opts.control \

@@ -36,7 +36,7 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench.harness import model_config
+    from bench import registry
     from repro.model import transformer as tf
     from repro.model.layers import Runtime
 
@@ -53,7 +53,7 @@ def main(argv=None) -> int:
             config = json.load(f)
         e = config["engine"]
         pages = args.pages or e["num_pages"]
-        cfg = model_config(config)
+        cfg = registry.arch(config, where=entry["file"]).model_config(config)
         rt = Runtime(attn_impl="pallas", interpret=False,
                      param_dtype=jnp.bfloat16,
                      activation_dtype=jnp.bfloat16)

@@ -24,6 +24,7 @@ from bench.tests import tiny  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 SEED = 2 ** 31 + 12345                   # larger than 32 signed bits hold
+DENSE = registry.arch({"arch": "dense"})
 
 
 # -- trace reduction -----------------------------------------------------------
@@ -45,7 +46,7 @@ def _events(pd, plane, line):
 
 
 def test_trace_busy_idle_and_programs(recorded):
-    red = trace_mod.reduce(recorded)
+    red = trace_mod.reduce(recorded, DENSE.KERNELS)
     win = _events(recorded, "/host:CPU", "python")
     (lo, hi, _), = [w for w in win if w[2] == "bench.window"]
     assert red.window_s == pytest.approx((hi - lo) * 1e-9)
@@ -69,7 +70,7 @@ def test_trace_busy_idle_and_programs(recorded):
 
 
 def test_trace_kernels_and_breakdown(recorded):
-    red = trace_mod.reduce(recorded)
+    red = trace_mod.reduce(recorded, DENSE.KERNELS)
     ops = _events(recorded, "/device:TPU:0", "XLA Ops")
     mods = _events(recorded, "/device:TPU:0", "XLA Modules")
 
@@ -98,19 +99,19 @@ def test_trace_kernels_and_breakdown(recorded):
 # -- work counts ----------------------------------------------------------------
 
 def test_work_hand_counts_one_layer():
-    s = work.Shapes(layers=1, d_model=8, heads=2, kv_heads=1, head_dim=4,
-                    d_ff=16, vocab=10, itemsize=2)
+    s = DENSE.Shapes(layers=1, d_model=8, heads=2, kv_heads=1, head_dim=4,
+                     d_ff=16, vocab=10, itemsize=2)
     # q 8x8, k 8x4, v 8x4, o 8x8, gate/up 8x16 each, down 16x8
     assert s.layer_matmul_params == 64 + 32 + 32 + 64 + 3 * 128
     # one token reading 5 keys: 2·params + 4·5·heads·dh, + unembed 2·8·10
-    assert work.decode_token_flops(s, 5) == 2 * 576 + 4 * 5 * 2 * 4 + 160
+    assert s.decode_token_flops(5) == 2 * 576 + 4 * 5 * 2 * 4 + 160
     # positions 2,3,4 of a prompt read 3,4,5 keys
-    assert work.prefill_flops(s, 2, 5) == \
+    assert s.prefill_flops(2, 5) == \
         2 * 576 * 3 + 4 * 2 * 4 * (3 + 4 + 5) + 160
-    assert work.prefill_flops(s, 5, 5) == 0
+    assert s.prefill_flops(5, 5) == 0
     # K and V of 5 positions of one kv head, q in and o out of 2 heads
-    assert work.paged_decode_bytes(s, 5) == (2 * 5 * 1 * 4 + 2 * 2 * 4) * 2
-    assert work.paged_decode_flops(s, 5) == 4 * 5 * 2 * 4
+    assert s.paged_decode_bytes(5) == (2 * 5 * 1 * 4 + 2 * 2 * 4) * 2
+    assert s.paged_decode_flops(5) == 4 * 5 * 2 * 4
     peak = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
     assert work.roofline_seconds(200, 50, peak) == 5.0
     assert work.roofline_seconds(2000, 5, peak) == 20.0
@@ -119,7 +120,7 @@ def test_work_hand_counts_one_layer():
 def test_work_shapes_from_configs():
     with open(os.path.join(ROOT, "bench", "configs",
                            "stablelm-1.6b.json")) as f:
-        s = work.Shapes.from_config(json.load(f))
+        s = DENSE.Shapes.from_config(json.load(f))
     # 24 layers x 51.4M matmul weights (4 x 2048² + 3 x 2048 x 5632): the
     # published 1.64B less the two 205.5M embedding tables
     assert s.layers * s.layer_matmul_params == 24 * 51_380_224
@@ -273,6 +274,7 @@ def test_registry_finds_new_pieces_from_files_alone(tmp_path):
         "silent": "def read(rec):\n    return None\n"})
     cell = registry.load_cell(root, "tiny.chat")
     assert cell.config["hidden_size"] == 64
+    assert cell.arch.KERNELS == DENSE.KERNELS
     assert cell.mix["loop"] == "open"
     assert cell.checks == {"max_logit_gap": 1e9, "mean_logit_gap": 1e9}
     assert [m["name"] for m in cell.end_to_end] == [
@@ -330,7 +332,7 @@ def _program_f32(config, seed):
     from repro.model import transformer as tf
     from repro.model.layers import Runtime
 
-    cfg = harness.model_config(config)
+    cfg = registry.arch(config).model_config(config)
     rt = Runtime(attn_impl="jnp", param_dtype=jnp.bfloat16,
                  activation_dtype=jnp.float32)
     # weights drawn and rounded as served (bf16), then run in float32
@@ -361,8 +363,8 @@ def test_reference_matches_the_engine(norm, kv_heads):
     with jax.default_matmul_precision("highest"):
         want = np.asarray(tf.forward(cfg, params, {"inputs": jnp.asarray(
             toks)}, rt))
-    got = reference.logits_at(config, SEED, list(toks),
-                              [np.arange(48)] * 2, 64)
+    got = reference.logits_at(registry.arch(config), config, SEED,
+                              list(toks), [np.arange(48)] * 2, 64)
     np.testing.assert_allclose(np.stack(got), want, atol=2e-3, rtol=2e-3)
 
     eng = AsyncServeEngine(cfg, params, slots=4, max_len=128, rt=rt,
@@ -377,7 +379,8 @@ def test_reference_matches_the_engine(norm, kv_heads):
     eng.serve_trace(reqs[:1])
     eng.serve_trace(reqs[1:])
     assert eng.stats["tokens_reused"] >= 2 * 32
-    gaps = harness.check(config, SEED, reqs, 128)["program"]
+    gaps = harness.check(registry.arch(config), config, SEED, reqs,
+                         128)["program"]
     assert gaps.size == 36
     assert gaps.max() < 1e-3
 

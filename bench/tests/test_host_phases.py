@@ -16,7 +16,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
-from bench import harness, registry, work  # noqa: E402
+from bench import harness, registry  # noqa: E402
 from bench import trace as trace_mod  # noqa: E402
 from bench.tests import tiny  # noqa: E402
 
@@ -24,6 +24,7 @@ FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                        "v5e_decode_prefill.xspace.pbtxt")
 SEED = 2 ** 31 + 4242
 CELL = "stablelm-1.6b.decode-heavy"
+KERNELS = registry.arch({"arch": "dense"}).KERNELS
 
 
 def _stats(**kw):
@@ -136,20 +137,20 @@ def _fields(red, labels=True):
 
 def test_engine_spans_leave_the_reduction_unchanged(traces):
     plain, spanned, gap_ns = traces
-    a, b = trace_mod.reduce(plain), trace_mod.reduce(spanned)
+    a = trace_mod.reduce(plain, KERNELS)
+    b = trace_mod.reduce(spanned, KERNELS)
     assert a.idle_gaps[0][1] == pytest.approx(gap_ns * 1e-9)
-    assert _fields(a) == _fields(b)
+    assert _fields(a, labels=False) == _fields(b, labels=False)
     assert a.idle_frac == b.idle_frac
 
 
-def test_a_gap_inside_an_engine_phase_takes_its_label(traces, monkeypatch):
-    """Where the reduction collects the program's ``engine.*`` spans
-    beside its own, the longest gap takes the innermost span's label and
-    every other field of the reduction reads as before."""
+def test_a_gap_inside_an_engine_phase_takes_its_label(traces):
+    """The reduction collects the program's ``engine.*`` spans beside its
+    own: the longest gap takes the innermost span's label and every other
+    field of the reduction reads as before."""
     plain, spanned, _ = traces
-    before = trace_mod.reduce(plain)
-    monkeypatch.setattr(trace_mod, "HOST_SPAN_PREFIX", ("bench.", "engine."))
-    after = trace_mod.reduce(spanned)
+    before = trace_mod.reduce(plain, KERNELS)
+    after = trace_mod.reduce(spanned, KERNELS)
     assert after.idle_gaps[0][0] == "engine.decode.harvest"
     assert before.idle_gaps[0][0] == "bench.pump"
     assert [x for x, _ in after.idle_gaps[1:3]] == ["bench.pump"] * 2
@@ -162,13 +163,14 @@ def test_engine_spans_leave_every_metric_unchanged(traces):
     rec = {"stats": _stats(decode_dispatches=2, prefill_dispatches=2,
                            decode_steps=17, tokens_decoded=130,
                            tokens_prefilled=320),
-           "slots": 8, "shapes": work.Shapes.from_config(cell.config),
+           "slots": 8, "shapes": cell.arch.Shapes.from_config(cell.config),
            "peak": registry.peaks("TPU v5 lite"),
            "decode_ctx": [200 + i for i in range(130)],
            "prefills": [(0, 256), (0, 64)], "decode_hbm_bytes": 2 ** 33}
-    a = registry.read_metrics(cell, dict(rec, trace=trace_mod.reduce(plain)))
-    b = registry.read_metrics(cell,
-                              dict(rec, trace=trace_mod.reduce(spanned)))
+    a = registry.read_metrics(
+        cell, dict(rec, trace=trace_mod.reduce(plain, KERNELS)))
+    b = registry.read_metrics(
+        cell, dict(rec, trace=trace_mod.reduce(spanned, KERNELS)))
     assert a == b
     assert {"decode_step_ms", "paged_decode_roofline",
             "device_idle_frac"} <= set(a)

@@ -1,12 +1,16 @@
 """A throwaway benchmark tree at smoke size, for CPU tests of the harness:
-one configuration, two mixes, two cells, written under a temporary root."""
+one configuration, two mixes, two cells, written under a temporary root,
+beside a copy of the benchmark's architecture modules."""
 from __future__ import annotations
 
 import json
 import os
+import shutil
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TINY_CONFIG = {
-    "source": "test", "model_type": "tiny",
+    "source": "test", "model_type": "tiny", "arch": "dense",
     "hidden_size": 64, "intermediate_size": 128,
     "num_attention_heads": 4, "num_key_value_heads": 2,
     "num_hidden_layers": 2, "vocab_size": 256,
@@ -29,12 +33,21 @@ TINY_MIXES = {
 
 
 def make_root(root: str, config: dict = None, gap_limit: float = 1e9,
-              mean_limit: float = 1e9, extra_metrics: dict = None) -> str:
+              mean_limit: float = 1e9, extra_metrics: dict = None,
+              extra_arches: dict = None) -> str:
     """Write BENCHMARK.json, the configuration, the mixes and the cells
-    under ``root``; returns ``root``."""
+    under ``root``, with the benchmark's architecture modules and any in
+    ``extra_arches`` (name -> source); returns ``root``."""
     config = config or TINY_CONFIG
     for d in ("configs", "traffic", "cells", "metrics"):
         os.makedirs(os.path.join(root, "bench", d), exist_ok=True)
+    shutil.copytree(os.path.join(BENCH, "arch"),
+                    os.path.join(root, "bench", "arch"), dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    for name, body in (extra_arches or {}).items():
+        with open(os.path.join(root, "bench", "arch", name + ".py"),
+                  "w") as f:
+            f.write(body)
     with open(os.path.join(root, "bench", "configs", "tiny.json"), "w") as f:
         json.dump(config, f)
     cells = []

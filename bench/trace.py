@@ -7,18 +7,24 @@ execution, named ``<jit name>(<fingerprint>)``; its line ``XLA Ops`` has
 one event per HLO operation run, named by the HLO text
 (``%fusion.12 = bf16[...] fusion(...)``), nested where an operation holds
 others (a ``while`` holds its body).  A Pallas kernel is a custom call
-whose text holds ``custom_call_target="tpu_custom_call"``; the kernel's
-own name is not in the event, so a kernel is known by the program that
-runs it.  The benchmark's host spans (``jax.profiler.TraceAnnotation``,
-named ``bench.*``) are on the ``/host:CPU`` plane, on the same clock.
+whose text holds ``custom_call_target="tpu_custom_call"``, and the
+operation is named after the kernel (``pallas_call(name=...)``):
+``%fusemax_decode_paged.7 = ...``.  The benchmark's host spans
+(``jax.profiler.TraceAnnotation``, named ``bench.*``) and the serving
+engine's (``engine.*``) are on the ``/host:CPU`` plane, on the same clock.
 
-All programs and kernels are picked out by the one name table below.
+Programs are picked out by the name table below; kernels by the table of
+the configuration's architecture module (``KERNELS`` in
+``bench/arch/<arch>.py``): kernel -> (program class, Pallas kernel name),
+an operation counting for a kernel where it runs inside a program of
+that class and its name, less its ``.<n>`` suffixes, is the kernel's.
 """
 from __future__ import annotations
 
 import bisect
 import collections
 import dataclasses
+import re
 from typing import Dict, List, Optional, Tuple
 
 #: program class -> prefix of the ``XLA Modules`` event name
@@ -26,13 +32,8 @@ PROGRAMS: Dict[str, str] = {
     "decode": "jit_loop(",
     "prefill": "jit_prefill_into_slots(",
 }
-#: kernel -> (program class that runs it, marker in the op's HLO text)
-KERNELS: Dict[str, Tuple[str, str]] = {
-    "paged_decode": ("decode", 'custom_call_target="tpu_custom_call"'),
-    "prefill_attention": ("prefill", 'custom_call_target="tpu_custom_call"'),
-}
-#: prefix of the benchmark's own host spans
-HOST_SPAN_PREFIX = "bench."
+#: prefixes of the host spans: the benchmark's own and the engine's phases
+HOST_SPAN_PREFIX = ("bench.", "engine.")
 WINDOW_SPAN = "bench.window"
 TOP = 10
 
@@ -73,6 +74,24 @@ def _short(name: str) -> str:
     return head
 
 
+def _op_base(name: str) -> str:
+    """The operation's name without its ``.<n>`` suffixes:
+    ``%fusemax_decode_paged.7 = ...`` -> ``fusemax_decode_paged``."""
+    return re.sub(r"(\.\d+)+$", "", name.split(" = ", 1)[0].lstrip("%"))
+
+
+def _classify(mod: Optional[str], name: str,
+              kernels: Dict[str, Tuple[str, str]]) -> Tuple[List[str], str]:
+    """The kernels of the table that the op ``name`` run inside the
+    program ``mod`` counts for, and its ``<program>/<op>`` label."""
+    cls = _program_class(mod) if mod else None
+    base = _op_base(name)
+    knames = [k for k, (kcls, pallas_name) in kernels.items()
+              if cls == kcls and base == pallas_name]
+    prog = mod.split("(", 1)[0] if mod else "?"
+    return knames, f"{prog}/{_short(name)}"
+
+
 def _program_class(module_name: str) -> Optional[str]:
     for cls, prefix in PROGRAMS.items():
         if module_name.startswith(prefix):
@@ -97,9 +116,10 @@ def _host_spans(pd) -> List[Tuple[float, float, str]]:
     return out
 
 
-def reduce(pd) -> Reduced:
+def reduce(pd, kernels: Dict[str, Tuple[str, str]]) -> Reduced:
     """Reduce a loaded ``jax.profiler.ProfileData`` over its
-    ``bench.window`` span (or, without one, over the device events)."""
+    ``bench.window`` span (or, without one, over the device events),
+    counting the ``kernels`` of the architecture's table."""
     host = _host_spans(pd)
     win = [(s, e) for s, e, n in host if n == WINDOW_SPAN]
     devices = [p for p in pd.planes if p.name.startswith("/device:TPU:")]
@@ -126,9 +146,10 @@ def reduce(pd) -> Reduced:
     busy_total = 0.0
     programs: Dict[str, float] = collections.Counter()
     runs: Dict[str, int] = collections.Counter()
-    kernels: Dict[str, float] = collections.Counter()
+    kernel_s: Dict[str, float] = collections.Counter()
     self_time: Dict[str, float] = collections.Counter()
     gaps_all: List[Tuple[float, float]] = []
+    seen: Dict[Tuple[Optional[str], str], Tuple[List[str], str]] = {}
     for mods, ops in per_chip:
         starts = [s for s, _, _ in mods]
         for s, e, name in mods:
@@ -161,13 +182,15 @@ def reduce(pd) -> Reduced:
                 continue
             intervals.append((max(s, lo), min(e, hi)))
             mod = module_of(s, e)
-            cls = _program_class(mod) if mod else None
-            for kname, (kcls, marker) in KERNELS.items():
-                if cls == kcls and marker in name:
-                    kernels[kname] += t
-            prog = mod.split("(", 1)[0] if mod else "?"
-            self_time[f"{prog}/{_short(name)}"] += max(0.0, st) * (
-                t / (e - s) if e > s else 1.0)
+            # a trace repeats each (program, op) name over many events:
+            # each is parsed once
+            hit = seen.get((mod, name))
+            if hit is None:
+                hit = seen[mod, name] = _classify(mod, name, kernels)
+            knames, op = hit
+            for kname in knames:
+                kernel_s[kname] += t
+            self_time[op] += max(0.0, st) * (t / (e - s) if e > s else 1.0)
         busy, pieces = _union_length(intervals or
                                      [(max(s, lo), min(e, hi))
                                       for s, e, _ in mods if e > lo
@@ -197,7 +220,7 @@ def reduce(pd) -> Reduced:
         chips=n,
         programs={k: v / n * 1e-9 for k, v in programs.items()},
         program_runs={k: v // n for k, v in runs.items()},
-        kernels={k: v / n * 1e-9 for k, v in kernels.items()},
+        kernels={k: v / n * 1e-9 for k, v in kernel_s.items()},
         device_ops=[(k, v / n * 1e-9)
                     for k, v in sorted(self_time.items(),
                                        key=lambda kv: kv[1],
